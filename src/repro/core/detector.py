@@ -72,7 +72,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import numerics as N, quant
 from repro.core.hog import HOGConfig, PAPER_HOG, grayscale
 from repro.core.stages import dense_blocks

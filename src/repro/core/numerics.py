@@ -101,10 +101,11 @@ NORM_RSQRT = {
 }
 
 
-def finish_blocks(v: Array, eps: float, norm: str) -> Array:
+def finish_blocks(v: Array, eps: float, norm: str, axis: int = -1) -> Array:
     """The block-normalize tail: (..., bd) raw block vectors -> (..., bd)
     L2-normalized f32 blocks (eq. 5), quantized onto the per-block int8
-    grid when norm == "fixed".
+    grid when norm == "fixed". `axis` holds the bd block components; the
+    Pallas kernels pass a leading axis (components-major planes).
 
     EVERY backend's normalize stage ends here -- ref (core/hog.py), the
     standalone block_norm kernel, dense_block_norm, and both fused
@@ -125,10 +126,12 @@ def finish_blocks(v: Array, eps: float, norm: str) -> Array:
     e = eps * quant.MAG_SCALE if norm == "fixed" else eps
     # e * e in Python (f64) then one f32 round -- bit-identical to the
     # historical `+ cfg.eps ** 2` weak-scalar add
-    ss = jnp.sum(v * v, axis=-1, keepdims=True) + jnp.float32(e * e)
+    sq = v * v
+    ss = (quant.plane_reduce(jnp.add, sq) if axis == 0
+          else jnp.sum(sq, axis=axis, keepdims=True)) + jnp.float32(e * e)
     out = v * rs(ss)
     if norm == "fixed":
-        out = quant.quantize_dequantize(out)
+        out = quant.quantize_dequantize(out, axis)
     return out
 
 

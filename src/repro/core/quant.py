@@ -32,6 +32,8 @@ one array keeps flowing through every existing detector/sharding seam.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -45,6 +47,22 @@ Q_MAX = 127.0        # symmetric int8 code range [(-)127 .. 127]
 MAG_SCALE = 0.5
 
 
+def plane_reduce(op, x: Array) -> Array:
+    """Keep-dims reduction over a LEADING axis as a chain of whole-plane
+    ops: the form Mosaic lowers inside a Pallas kernel, where a vector
+    reduction over a leading axis does not lower."""
+    return functools.reduce(op, list(x))[None]
+
+
+def _block_scale(v: Array, axis: int):
+    """Per-block scale max|v|/127 (kept dims) and its zero-safe divisor."""
+    a = jnp.abs(v)
+    m = plane_reduce(jnp.maximum, a) if axis == 0 \
+        else jnp.max(a, axis=axis, keepdims=True)
+    scale = m * jnp.float32(1.0 / Q_MAX)
+    return scale, jnp.where(scale > 0, scale, jnp.float32(1.0))
+
+
 def quantize_blocks(v: Array):
     """(..., bd) f32 block vectors -> (int8 codes, (...) f32 per-block scale).
 
@@ -52,9 +70,7 @@ def quantize_blocks(v: Array):
     zero codes. Block-norm output is nonnegative, but abs() keeps the
     quantizer total for any caller.
     """
-    m = jnp.max(jnp.abs(v), axis=-1, keepdims=True)
-    scale = m * jnp.float32(1.0 / Q_MAX)
-    safe = jnp.where(scale > 0, scale, jnp.float32(1.0))
+    scale, safe = _block_scale(v, -1)
     q = jnp.rint(v / safe).astype(jnp.int8)
     return q, scale[..., 0]
 
@@ -64,11 +80,15 @@ def dequantize_blocks(q: Array, scale: Array) -> Array:
     return q.astype(jnp.float32) * scale[..., None]
 
 
-def quantize_dequantize(v: Array) -> Array:
+def quantize_dequantize(v: Array, axis: int = -1) -> Array:
     """Round v onto its per-block int8 grid (the fixed chain's public
-    f32 output: exactly the values the int8 scoring path reconstructs)."""
-    q, scale = quantize_blocks(v)
-    return dequantize_blocks(q, scale)
+    f32 output: exactly the values the int8 scoring path reconstructs).
+
+    `axis` holds the block components (the Pallas kernels keep them on a
+    leading axis). The codes stay in f32: rint lands on integers in
+    [-127, 127], so the values equal an int8 round trip."""
+    scale, safe = _block_scale(v, axis)
+    return jnp.rint(v / safe) * scale
 
 
 def quantize_weight_columns(wt: Array):

@@ -12,13 +12,14 @@ rsqrt -- the same approximation baked into silicon (DESIGN.md §2).
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core import numerics as N
-from repro.kernels.common import INTERPRET, cdiv
+from repro.kernels.common import cdiv, resolve_interpret
 
 #: back-compat alias -- the canonical NR rsqrt (and the whole normalize
 #: tail) lives in core/numerics.py, shared by every backend
@@ -40,7 +41,7 @@ def _kernel(hist_ref, out_ref, *, block: int, eps: float, mode: str):
                                    "interpret"))
 def block_norm(hist: jax.Array, block: int = 2, eps: float = 1e-2,
                mode: str = "rsqrt", block_b: int = 8,
-               interpret: bool = INTERPRET) -> jax.Array:
+               interpret: Optional[bool] = None) -> jax.Array:
     B, ch, cw, bins = hist.shape
     bh, bw = ch - block + 1, cw - block + 1
     bd = block * block * bins
@@ -51,5 +52,5 @@ def block_norm(hist: jax.Array, block: int = 2, eps: float = 1e-2,
         in_specs=[pl.BlockSpec((tb, ch, cw, bins), lambda i: (i, 0, 0, 0))],
         out_specs=pl.BlockSpec((tb, bh, bw, bd), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, bh, bw, bd), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(hist)

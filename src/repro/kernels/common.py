@@ -1,17 +1,33 @@
 """Shared Pallas kernel utilities."""
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 
-# TPU is the compile target; this container is CPU-only, so kernels are
-# validated with the Pallas interpreter (executes the kernel body in
-# Python with the same BlockSpec pipeline semantics).
-INTERPRET = jax.default_backend() == "cpu"
+LANE = 128                       # TPU vector lane width / MXU tile edge
 
-# v5e geometry the BlockSpecs are sized for
-VMEM_BYTES = 128 * 1024 * 1024   # 128 MiB VMEM per core (v5e: 128MB unified)
-LANE = 128                       # vector lane width / MXU tile edge
-SUBLANE = 8                      # f32 sublane height
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """Interpret mode for one kernel trace.
+
+    An explicit bool wins: a test compiles the real kernel for a
+    described TPU from a CPU host with `interpret=False`. Otherwise the
+    platform decides when the kernel is traced -- never at import, before
+    the caller has picked a platform: the CPU runs the Pallas
+    interpreter, the TPU runs the compiled Mosaic kernel, and any other
+    platform is refused instead of silently interpreting.
+    """
+    if interpret is not None:
+        return interpret
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(
+        f"the Pallas kernels target the TPU (compiled) or the CPU "
+        f"(interpreted); no path exists for platform {platform!r}")
 
 
 def cdiv(a: int, b: int) -> int:

@@ -21,13 +21,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import INTERPRET, cdiv
+from repro.kernels.common import cdiv, resolve_interpret
 
 NEG_INF = -1e30
 
@@ -86,7 +87,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, block_q: int = 512,
                     block_k: int = 512,
-                    interpret: bool = INTERPRET) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, H, S, hd); k, v: (B, K, S, hd), H % K == 0 -> (B, H, S, hd)."""
     B, H, S, hd = q.shape
     K = k.shape[1]
@@ -122,7 +123,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((bq,), jnp.float32),      # running sum
             pltpu.VMEM((bq, hd), jnp.float32),   # output accumulator
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q.reshape(B * H, S, hd),
       k.reshape(B * K, S, hd),
       v.reshape(B * K, S, hd))

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +29,7 @@ from jax.experimental import pallas as pl
 
 from repro.core.cordic import (ANG_180, ATAN_LUT_DEG, ATAN_LUT_FIXED,
                                MAG_FRAC_BITS, _INV_GAIN_HALF, cordic_gain)
-from repro.kernels.common import INTERPRET, cdiv
+from repro.kernels.common import cdiv, resolve_interpret
 
 _BOUNDARIES = tuple((math.cos(math.radians(20.0 * (k + 1))),
                      math.sin(math.radians(20.0 * (k + 1))))
@@ -137,7 +138,7 @@ def _kernel(gray_ref, mag_ref, bin_ref, *, mode: str):
 
 @partial(jax.jit, static_argnames=("mode", "block_b", "interpret"))
 def hog_gradient(gray: jax.Array, mode: str = "sector",
-                 block_b: int = 8, interpret: bool = INTERPRET):
+                 block_b: int = 8, interpret: Optional[bool] = None):
     """(B, H, W) f32 -> (mag, bin) each (B, H-2, W-2)."""
     B, H, W = gray.shape
     tb = min(block_b, B)
@@ -155,5 +156,5 @@ def hog_gradient(gray: jax.Array, mode: str = "sector",
             pl.BlockSpec((tb, H - 2, W - 2), lambda i: (i, 0, 0)),
         ),
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(gray)

@@ -12,12 +12,13 @@ accumulation, the canonical Pallas matmul pattern).
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import INTERPRET, cdiv, round_up, LANE
+from repro.kernels.common import LANE, cdiv, resolve_interpret, round_up
 
 
 def _kernel(x_ref, w_ref, out_ref):
@@ -37,7 +38,7 @@ def _kernel(x_ref, w_ref, out_ref):
 @partial(jax.jit, static_argnames=("block_b", "block_f", "interpret"))
 def svm_scores(feats: jax.Array, w: jax.Array, bias: jax.Array,
                block_b: int = 128, block_f: int = 512,
-               interpret: bool = INTERPRET) -> jax.Array:
+               interpret: Optional[bool] = None) -> jax.Array:
     B, F = feats.shape
     Bp = round_up(B, 8)
     tb = min(block_b, Bp)
@@ -56,7 +57,7 @@ def svm_scores(feats: jax.Array, w: jax.Array, bias: jax.Array,
         ],
         out_specs=pl.BlockSpec((tb, 1), lambda i, k: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Bp, 1), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(feats, wp.reshape(Fp, 1))
     return out[:B, 0] + bias
 
@@ -78,7 +79,7 @@ def _score_kernel(x_ref, w_ref, out_ref):
 
 @partial(jax.jit, static_argnames=("block_m", "interpret"))
 def score_matmul(flat: jax.Array, wt: jax.Array, block_m: int = 512,
-                 interpret: bool = INTERPRET) -> jax.Array:
+                 interpret: Optional[bool] = None) -> jax.Array:
     """(M, K) block rows @ (K, N) per-offset weights -> (M, N) f32.
 
     Accepts f32 or bf16 inputs (the perf preset's bf16 descriptors);
@@ -103,7 +104,7 @@ def score_matmul(flat: jax.Array, wt: jax.Array, block_m: int = 512,
         ],
         out_specs=pl.BlockSpec((tm, Np), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(flat, wt)
     return out[:M, :N]
 
@@ -116,7 +117,7 @@ def _score_kernel_i8(x_ref, w_ref, out_ref):
 
 @partial(jax.jit, static_argnames=("block_m", "interpret"))
 def score_matmul_int8(q: jax.Array, wq: jax.Array, block_m: int = 512,
-                      interpret: bool = INTERPRET) -> jax.Array:
+                      interpret: Optional[bool] = None) -> jax.Array:
     """(M, K) int8 block rows @ (K, N) int8 weights -> (M, N) int32.
 
     The fixed-mode twin of `score_matmul`: codes in [-127, 127] over
@@ -147,6 +148,6 @@ def score_matmul_int8(q: jax.Array, wq: jax.Array, block_m: int = 512,
         ],
         out_specs=pl.BlockSpec((tm, Np), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, wq)
     return out[:M, :N]

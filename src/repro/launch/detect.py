@@ -7,6 +7,8 @@ after training (checkpoint/manager.py atomic layout), `--load DIR`
 restores them (falling back to training, then saving if --save was also
 given -- so `--load D --save D` is "train once, reuse forever").
 
+Exits nonzero when no scene produced a detection.
+
 Usage: PYTHONPATH=src python -m repro.launch.detect
            [--scenes 3] [--fast] [--backend ref|kernel|fused]
            [--preset paper|faithful|perf|default]
@@ -91,7 +93,7 @@ def main(argv=None):
             session.save(args.save)
             print(f"saved SVM params to {args.save}")
 
-    hits = 0
+    hits = n_dets = 0
     for i in range(args.scenes):
         scene, truth = make_scene(rng, 320, 240, n_people=2)
         t0 = time.perf_counter()
@@ -100,6 +102,7 @@ def main(argv=None):
         ms = (time.perf_counter() - t0) * 1e3
         tag = "compile+run" if i == 0 else "steady"
         sat = " [top-k saturated]" if result.saturated else ""
+        n_dets += len(dets)
         print(f"scene {i}: {len(truth)} people, {len(dets)} detections "
               f"({ms:.1f} ms {tag}){sat}")
         for d in dets[:4]:
@@ -117,6 +120,9 @@ def main(argv=None):
     plat = stats["platform"]
     print(f"platform: {plat['backend']} x{plat['device_count']} "
           f"x64={plat['x64']} jax={plat['jax_version']}")
+    if n_dets == 0:
+        print("no scene produced a detection", file=sys.stderr)
+        return 1
     return 0
 
 

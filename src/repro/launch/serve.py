@@ -9,7 +9,8 @@ the layer-stacked KV(/SSM) cache and print tokens/s.
 Detection mode: --detect builds a repro.api DetectionSession (training
 a quick SVM or loading one with --load), starts session.serve() -- the
 micro-batching DetectionService -- streams synthetic frames through it,
-and prints per-frame latency, saturation, and service stats.
+and prints per-frame latency, saturation, and service stats. It exits
+nonzero when any frame came back with an error.
 
     PYTHONPATH=src python -m repro.launch.serve --detect [--frames 6]
         [--preset paper] [--load DIR]
@@ -113,6 +114,10 @@ def _detect_smoke(args) -> int:
         print(f"chaos         fired={opts['faults'].fired} "
               f"errors={n_err} all_resolved={resolved}")
         return 0 if resolved else 1
+    if n_err:
+        print(f"{n_err} of {len(frames)} frames came back with an error",
+              file=sys.stderr)
+        return 1
     return 0
 
 

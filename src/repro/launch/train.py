@@ -20,6 +20,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import ARCH_IDS, get_config
@@ -52,7 +53,8 @@ def main(argv=None):
     opt = OptConfig(lr=args.lr, warmup_steps=10, total_steps=args.steps)
 
     if args.ddp:
-        mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+        mesh = jax.make_mesh((len(jax.devices()),), ("data",),
+                             axis_types=(AxisType.Auto,))
         state = init_ddp_state(cfg, jax.random.PRNGKey(0))
         step_fn = jax.jit(make_ddp_train_step(cfg, opt, mesh,
                                               compress=args.compress))

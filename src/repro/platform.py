@@ -31,6 +31,12 @@ Environment knobs consumed by `apply()`:
     REPRO_AUTOTUNE_CACHE   autotune disk-cache path ("" disables;
                            resolved by `autotune_cache_path()`)
 
+JAX's persistent compile cache: where `JAX_COMPILATION_CACHE_DIR` is
+set, jax reads it itself and no directory is set in code; otherwise
+apply() points the cache at the fixed `<checkout>/.jax_cache`
+(`compile_cache_dir()`), so every process of one checkout, and a later
+run of it, finds what an earlier one compiled.
+
 `describe()` snapshots the resolved environment (backend, device count,
 x64, flags, seed, what apply() changed) for BENCH json rows, serve
 stats, and metrics streams -- so every recorded number carries the
@@ -38,17 +44,23 @@ environment it was measured under. `is_main()` is the HomebrewNLP-style
 rank-0 guard (`jax.process_index() == 0`) that the metrics emitter and
 the future multi-host path share.
 
-jax is only imported lazily (describe / is_main): importing this module
-must stay legal BEFORE jax init, which is the whole point.
+jax is imported only after the env knobs are applied (the compile-cache
+setting, describe, is_main) and no backend is created at import:
+importing this module must stay legal BEFORE jax init, which is the
+whole point.
 """
 from __future__ import annotations
 
 import os
+import pathlib
 import sys
 import warnings
 from typing import MutableMapping, Optional
 
 _FORCE_FLAG = "xla_force_host_platform_device_count"
+
+#: the checkout this package runs from (src/repro/platform.py -> root)
+CHECKOUT = pathlib.Path(__file__).resolve().parents[2]
 
 #: what apply() changed, keyed by knob -- doubles as the idempotence
 #: guard (a non-None value means apply() already ran for this process)
@@ -170,9 +182,30 @@ def apply(env: Optional[MutableMapping] = None,
         env.setdefault("JAX_PLATFORMS", plat)
         applied["jax_platforms"] = env["JAX_PLATFORMS"]
 
+    cache = compile_cache_dir(env)
+    if cache is not None:
+        applied["compile_cache_dir"] = cache
+        if env is os.environ:
+            # last: jax reads JAX_PLATFORMS when it is first imported
+            import jax
+            jax.config.update("jax_compilation_cache_dir", cache)
+
     if env is os.environ:
         _APPLIED = applied
     return applied
+
+
+def compile_cache_dir(env: Optional[MutableMapping] = None
+                      ) -> Optional[str]:
+    """The compile-cache directory this module sets in code: None when
+    $JAX_COMPILATION_CACHE_DIR is set (jax uses it as given), else the
+    fixed `<checkout>/.jax_cache`. Never a temporary, per-process or
+    per-run name, so that later runs and other processes of the same
+    checkout find the entries."""
+    env = os.environ if env is None else env
+    if env.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    return str(CHECKOUT / ".jax_cache")
 
 
 def hermetic_autotune(env: Optional[MutableMapping] = None) -> None:
@@ -245,6 +278,7 @@ def describe() -> dict:
         "xla_flags": os.environ.get("XLA_FLAGS", ""),
         "forced_host_devices": forced_host_devices(),
         "autotune_cache": autotune_cache_path(),
+        "compile_cache": jax.config.jax_compilation_cache_dir,
         "seed": default_seed(),
         "applied": dict(_APPLIED or {}),
     }

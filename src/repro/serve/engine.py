@@ -237,6 +237,9 @@ class DetectionService:
                       # -------------------- resilience telemetry (§14)
                       "frame_answers": 0,       # every resolved future
                       "frame_errors": 0,        # error-payload answers
+                      # batched programs that raised and were re-run
+                      # frame by frame
+                      "batch_fallbacks": 0,
                       "deadline_shed": 0,       # shed before compute
                       "retries": 0,             # in-flight re-queues
                       "restarts": 0,            # supervised respawns
@@ -704,7 +707,10 @@ class DetectionService:
                             for res in results]
             except Exception:
                 # batch failed as a whole: fall back to per-frame so one
-                # poisonous frame cannot fail its innocent batch-mates
+                # poisonous frame cannot fail its innocent batch-mates;
+                # counted, so a batched program that cannot compile or
+                # run on the device is visible, not silently replaced
+                self.stats["batch_fallbacks"] += 1
                 dets_per = []
                 for r in group:
                     try:

@@ -20,3 +20,9 @@ from repro import platform  # noqa: E402  (applies REPRO_* at import)
 # autotune tests assert on); tests of the disk cache itself monkeypatch
 # this to a tmp file. In-memory autotune behavior is unchanged.
 platform.hermetic_autotune()
+
+# hermetic compiles: the persistent compile cache serves the program's
+# own runs; tests compile fresh and write nothing into the checkout
+import jax  # noqa: E402
+
+jax.config.update("jax_enable_compilation_cache", False)

@@ -5,6 +5,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.sharding import AxisType
 
 from repro.configs import get_config
 from repro.models.attention import attention, banded_attention
@@ -65,7 +66,8 @@ def test_banded_forward_matches_baseline_forward():
     batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, 32),
                                           0, cfg.vocab)}
     base = forward(params, batch, cfg, None)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     ctx = ShardingCtx(mesh=mesh, dp_axes=("data",), banded=True)
     band = forward(params, batch, cfg, ctx)
     np.testing.assert_allclose(

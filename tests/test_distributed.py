@@ -14,7 +14,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+
+AUTO2 = (AxisType.Auto,) * 2
 
 multi = pytest.mark.skipif(len(jax.devices()) < 8,
                            reason="needs 8 host devices "
@@ -22,7 +24,7 @@ multi = pytest.mark.skipif(len(jax.devices()) < 8,
 
 
 def _mesh():
-    return jax.make_mesh((4, 2), ("data", "model"))
+    return jax.make_mesh((4, 2), ("data", "model"), axis_types=AUTO2)
 
 
 @multi
@@ -104,7 +106,7 @@ def test_ddp_compressed_matches_uncompressed_direction():
     from repro.train.optimizer import OptConfig
     from repro.train.train_step import init_ddp_state, make_ddp_train_step
     cfg = get_config("mamba2-130m", smoke=True)
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
     batch = {"tokens": jnp.ones((8, 32), jnp.int32),
              "labels": jnp.ones((8, 32), jnp.int32)}
     opt = OptConfig(lr=1e-2, warmup_steps=1)
@@ -130,7 +132,7 @@ def test_checkpoint_elastic_restore(tmp_path):
     """Save on a (4,2) mesh, restore onto (2,4) and (8,1): elastic."""
     from repro.checkpoint.manager import CheckpointManager
     mgr = CheckpointManager(str(tmp_path), keep=2)
-    mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+    mesh_a = jax.make_mesh((4, 2), ("data", "model"), axis_types=AUTO2)
     tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8),
             "step": jnp.int32(7)}
     tree = jax.device_put(tree, {
@@ -138,7 +140,7 @@ def test_checkpoint_elastic_restore(tmp_path):
         "step": NamedSharding(mesh_a, P())})
     mgr.save(100, tree)
     assert mgr.latest_step() == 100
-    mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+    mesh_b = jax.make_mesh((2, 4), ("data", "model"), axis_types=AUTO2)
     target = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
     sh_b = {"w": NamedSharding(mesh_b, P("data", "model")),
@@ -170,7 +172,7 @@ def test_checkpoint_async_and_gc(tmp_path):
 def test_compressed_psum_accuracy():
     from repro.train.grad_compress import compressed_psum_mean
     from jax import shard_map
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
     x = jax.random.normal(jax.random.PRNGKey(0), (8, 4096), jnp.float32)
 
     def body(xl):

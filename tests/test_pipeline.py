@@ -3,6 +3,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.sharding import AxisType
 
 from repro.train.pipeline import bubble_fraction, gpipe_apply
 
@@ -31,7 +32,7 @@ def _sequential(params, x_micro):
 
 @multi
 def test_gpipe_matches_sequential():
-    mesh = jax.make_mesh((4,), ("pipe",))
+    mesh = jax.make_mesh((4,), ("pipe",), axis_types=(AxisType.Auto,))
     L, d, M, B = 8, 16, 6, 2
     params = _params(L, d, jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(1), (M, B, d))
@@ -44,7 +45,7 @@ def test_gpipe_matches_sequential():
 @multi
 def test_gpipe_backward_matches_sequential():
     """GPipe backward (autodiff through ppermute) == sequential grads."""
-    mesh = jax.make_mesh((4,), ("pipe",))
+    mesh = jax.make_mesh((4,), ("pipe",), axis_types=(AxisType.Auto,))
     L, d, M, B = 4, 8, 4, 2
     params = _params(L, d, jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(1), (M, B, d))

@@ -138,6 +138,25 @@ def test_hermetic_autotune_is_setdefault():
     assert env["REPRO_AUTOTUNE_CACHE"] == "/keep/me.json"
 
 
+def test_compile_cache_env_var_means_nothing_set_in_code():
+    env = {"JAX_COMPILATION_CACHE_DIR": "/operator/cache"}
+    assert platform.compile_cache_dir(env) is None
+    assert "compile_cache_dir" not in platform.apply(env)
+    assert env == {"JAX_COMPILATION_CACHE_DIR": "/operator/cache"}
+
+
+def test_compile_cache_unset_is_fixed_in_checkout_path():
+    root = pathlib.Path(__file__).resolve().parent.parent
+    first = platform.compile_cache_dir({})
+    assert first == platform.compile_cache_dir({}) == str(root / ".jax_cache")
+    assert platform.apply({})["compile_cache_dir"] == first
+    # this process resolved through the same rule at import
+    import jax
+    want = os.environ.get("JAX_COMPILATION_CACHE_DIR") or first
+    assert jax.config.jax_compilation_cache_dir == want
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+
+
 def test_default_seed():
     assert platform.default_seed({}) == 0
     assert platform.default_seed({"REPRO_SEED": "42"}) == 42
