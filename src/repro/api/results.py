@@ -42,6 +42,7 @@ import jax
 import numpy as np
 
 from repro.core.detector import DecodeTables
+from repro.obs import spans
 
 
 class Detections:
@@ -153,13 +154,16 @@ class Detections:
         return int(np.shape(self._scores)[0])
 
     def frame(self, i: int) -> "Detections":
-        """Slice one frame out of a batched result (no host sync)."""
+        """Slice one frame out of a batched result (no host sync; on the
+        device each slice is a small program of its own). Span:
+        `detect.slice`."""
         if not self.batched:
             raise ValueError("frame() on a single-frame Detections")
         lists = None if self._lists is None else [self._lists[i]]
-        return Detections(self._scores[i], self._index[i], self._keep[i],
-                          self._n_valid[i], self._tables, _lists=lists,
-                          classes=self._classes)
+        with spans.span("detect.slice"):
+            return Detections(self._scores[i], self._index[i],
+                              self._keep[i], self._n_valid[i], self._tables,
+                              _lists=lists, classes=self._classes)
 
     def for_class(self, c) -> "Detections":
         """Slice one head (by name or index) out of a multi-class
@@ -186,7 +190,8 @@ class Detections:
         was dropped BEFORE NMS. bool for a frame, (B,) array per batch;
         with a class axis the array keeps it ((K,) / (B, K)), one flag
         per head."""
-        n_valid = np.asarray(self._n_valid)
+        with spans.span("detect.fetch"):
+            n_valid = np.asarray(self._n_valid)
         if self.batched or self._classes is not None:
             return n_valid > self._tables.k
         return bool(int(n_valid) > self._tables.k)
@@ -230,18 +235,22 @@ class Detections:
         return merged
 
     def _decoded(self) -> list:
+        """The host decode, cached. Spans: `detect.fetch`, the
+        device-to-host copies (which wait for the device), and
+        `detect.decode`, the Python decode."""
         if self._lists is None:
-            if self.batched:
+            with spans.span("detect.fetch"):
                 top = np.asarray(self._scores)
                 idx = np.asarray(self._index)
                 kp = np.asarray(self._keep)
                 nv = np.asarray(self._n_valid)
-                self._lists = [self._decode_frame(top[i], idx[i], kp[i],
-                                                  nv[i])
-                               for i in range(top.shape[0])]
-            else:
-                self._lists = [self._decode_frame(
-                    self._scores, self._index, self._keep, self._n_valid)]
+            with spans.span("detect.decode"):
+                if self.batched:
+                    self._lists = [self._decode_frame(top[i], idx[i], kp[i],
+                                                      nv[i])
+                                   for i in range(top.shape[0])]
+                else:
+                    self._lists = [self._decode_frame(top, idx, kp, nv)]
         return self._lists
 
     def to_list(self):

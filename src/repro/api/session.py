@@ -36,10 +36,12 @@ import numpy as np
 
 from repro.api.config import PipelineConfig, presets
 from repro.api.results import Detections
+from repro.core import detector as _detector
 from repro.core.detector import (FrameDetector, _batch_fn, _frame_program,
                                  _sharded_batch_fn, _single_fn,
                                  _tile_local_fn, _tiled_batch_fn,
-                                 _tiled_single_fn)
+                                 _tiled_single_fn, program_loads,
+                                 ready_programs)
 from repro.core.heads import HeadRegistry
 from repro.core.hog import hog_descriptor
 from repro.core.svm import SVMParams, train_svm
@@ -302,18 +304,19 @@ class DetectionSession:
         entry compiles the SHARDED per-bucket program (including the
         pad-and-mask variant when B does not divide the mesh), so a
         serving deployment warms the same multi-device executables its
-        microbatcher will dispatch. Returns cache_stats()."""
+        microbatcher will dispatch. What readying each new program cost
+        is counted once (`cache_stats()["program_loads"]`). Returns
+        cache_stats()."""
         for s in shapes:
             s = tuple(int(v) for v in s)
-            if len(s) == 2:
-                d = self.detector.detect_raw(np.zeros(s + (3,), np.uint8))
-            elif len(s) == 3:
-                d = self.detector.detect_batch_raw(
-                    np.zeros(s + (3,), np.uint8))
-            else:
+            if len(s) not in (2, 3):
                 raise ValueError(
                     f"warmup shape must be (h, w) or (B, h, w), got {s}")
-            d.block_until_ready()
+            zeros = np.zeros(s + (3,), np.uint8)
+            with ready_programs():
+                d = self.detector.detect_raw(zeros) if len(s) == 2 \
+                    else self.detector.detect_batch_raw(zeros)
+                d.block_until_ready()
             self._warm.add(s)
         return self.cache_stats()
 
@@ -326,6 +329,10 @@ class DetectionSession:
         "platform" block (repro.platform.describe()) records the
         environment -- backend, device count, x64, XLA flags -- so a
         checked-in stats dump is attributable to the host that made it.
+        "program_loads" lists, for each (bucket, B) program `warmup`
+        readied, its seconds (compile or persistent-cache load plus the
+        first run), whether it "compiled" or "loaded", and the seconds
+        of a first-use batch-schedule probe ("probe_s").
         """
         from repro import platform
         from repro.core import autotune_cache
@@ -365,6 +372,7 @@ class DetectionSession:
                      "frame_parallel": self.config.detector.frame_parallel,
                      "tile_devices": tiles},
             "autotune": autotune_cache.stats(),
+            "program_loads": program_loads(),
             "platform": platform.describe(),
             "warmed": sorted(self._warm),
             "calls": dict(self._stats),
@@ -380,4 +388,6 @@ class DetectionSession:
         _tile_local_fn.cache_clear()
         _tiled_single_fn.cache_clear()
         _tiled_batch_fn.cache_clear()
+        _detector._PROGRAMS.clear()
+        _detector._LOADS.clear()
         self._warm.clear()

@@ -60,10 +60,22 @@ score grid (exact descriptor halo) or by whole scale-groups
 (core/tiling.py:merge_topk) plus one nms_keep pass reproduce the
 untiled result box-identically (tests/test_tiled.py), taking worst-case
 single-frame latency from one chip to all of them (DESIGN.md §11).
+
+STAGES. Each stage of the frame program is wrapped in `jax.named_scope`
+where it is defined, so every variant (single, batched, sharded, tiled)
+carries the names in its HLO metadata (`op_name`) and in the device
+trace: `gray` (_prep_frame), `resize` (each pyramid level), `hog`
+(scene_blocks), `score/matmul` and `score/collate` (score_blocks),
+`select` (threshold and top-k) and `nms` (nms_keep). The scopes change
+metadata only, not the operations or their names. `op_scopes(batch)`
+reads them back from the compiled text of the programs dispatched.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import re
+import time
 from functools import lru_cache, partial
 from typing import Callable, List, Optional, Sequence, Tuple
 
@@ -77,6 +89,7 @@ from repro.core import numerics as N, quant
 from repro.core.hog import HOGConfig, PAPER_HOG, grayscale
 from repro.core.stages import dense_blocks
 from repro.core.svm import SVMParams
+from repro.obs import spans
 
 Array = jax.Array
 
@@ -167,7 +180,8 @@ def scene_blocks(gray: Array, cfg: HOGConfig,
     selects ref (pure jnp) or the dense-grid Pallas kernel/fused
     implementations (kernels/dense_grad_hist.py et al.).
     """
-    return dense_blocks(gray, cfg, backend)
+    with jax.named_scope("hog"):
+        return dense_blocks(gray, cfg, backend)
 
 
 def score_blocks(blocks: Array, w: Array, b: Array,
@@ -202,6 +216,23 @@ def score_blocks(blocks: Array, w: Array, b: Array,
     if w.ndim == 2:                                     # stacked (K, F) heads
         return _score_blocks_multi(flat, w, b, cfg, use_kernel,
                                    BH, BW, ph, pw)
+    with jax.named_scope("score/matmul"):
+        contrib = _score_contrib(flat, w, cfg, use_kernel, bh * bw)
+    with jax.named_scope("score/collate"):
+        contrib = contrib.reshape(BH, BW, bh * bw)
+        out = jnp.zeros((ph, pw), jnp.float32)
+        for di in range(bh):                            # static 15x7 unroll
+            for dj in range(bw):
+                out = out + contrib[di:di + ph, dj:dj + pw, di * bw + dj]
+        return out + b
+
+
+def _score_contrib(flat: Array, w: Array, cfg: HOGConfig, use_kernel: bool,
+                   offsets: int) -> Array:
+    """The scoring matmul: (BH*BW, 36) block rows against the (36,
+    offsets) per-offset weights -> (BH*BW, offsets) partial sums, with
+    offsets 105, or 105*K head-major columns for K stacked heads."""
+    bd = flat.shape[-1]
     if N.spec_for(cfg).quantized:
         # fixed mode: the incoming grid is dequantized int8 (exactly
         # q * scale, numerics.finish_blocks), so requantizing recovers
@@ -212,7 +243,7 @@ def score_blocks(blocks: Array, w: Array, b: Array,
         # under any blocking; the rank-1 f32 rescale is elementwise with
         # a fixed multiply order (quant.rescale_scores).
         q, s_rows = quant.quantize_blocks(flat)
-        wt = w.reshape(bh * bw, bd).T.astype(jnp.float32)
+        wt = w.reshape(offsets, bd).T.astype(jnp.float32)
         wq, s_cols = quant.quantize_weight_columns(wt)
         if use_kernel:
             from repro.kernels.svm_matmul import score_matmul_int8
@@ -221,22 +252,14 @@ def score_blocks(blocks: Array, w: Array, b: Array,
             ci = jax.lax.dot_general(
                 q, wq, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.int32)
-        contrib = quant.rescale_scores(ci, s_rows, s_cols)
-    else:
-        wt = w.reshape(bh * bw, bd).T.astype(blocks.dtype)  # (36, 105)
-        if use_kernel:
-            from repro.kernels.svm_matmul import score_matmul
-            contrib = score_matmul(flat, wt)
-        else:
-            contrib = jax.lax.dot_general(
-                flat, wt, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-    contrib = contrib.reshape(BH, BW, bh * bw)
-    out = jnp.zeros((ph, pw), jnp.float32)
-    for di in range(bh):                                # static 15x7 unroll
-        for dj in range(bw):
-            out = out + contrib[di:di + ph, dj:dj + pw, di * bw + dj]
-    return out + b
+        return quant.rescale_scores(ci, s_rows, s_cols)
+    wt = w.reshape(offsets, bd).T.astype(flat.dtype)        # (36, 105)
+    if use_kernel:
+        from repro.kernels.svm_matmul import score_matmul
+        return score_matmul(flat, wt)
+    return jax.lax.dot_general(
+        flat, wt, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
 
 def _score_blocks_multi(flat: Array, w: Array, b: Array, cfg: HOGConfig,
@@ -251,37 +274,18 @@ def _score_blocks_multi(flat: Array, w: Array, b: Array, cfg: HOGConfig,
     collate runs the same static 15x7 unroll per head plane, in the
     same accumulation order as the single-head path."""
     bh, bw = cfg.blocks_hw
-    bd = flat.shape[-1]
     K = w.shape[0]
-    if N.spec_for(cfg).quantized:
-        q, s_rows = quant.quantize_blocks(flat)
+    with jax.named_scope("score/matmul"):
         # (K, bh*bw, bd) -> (bd, K*bh*bw), head-major columns
-        wt = w.reshape(K * bh * bw, bd).T.astype(jnp.float32)
-        wq, s_cols = quant.quantize_weight_columns(wt)
-        if use_kernel:
-            from repro.kernels.svm_matmul import score_matmul_int8
-            ci = score_matmul_int8(q, wq)
-        else:
-            ci = jax.lax.dot_general(
-                q, wq, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.int32)
-        contrib = quant.rescale_scores(ci, s_rows, s_cols)
-    else:
-        wt = w.reshape(K * bh * bw, bd).T.astype(flat.dtype)
-        if use_kernel:
-            from repro.kernels.svm_matmul import score_matmul
-            contrib = score_matmul(flat, wt)
-        else:
-            contrib = jax.lax.dot_general(
-                flat, wt, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-    contrib = contrib.reshape(BH, BW, K, bh * bw)
-    out = jnp.zeros((K, ph, pw), jnp.float32)
-    for di in range(bh):                                # static 15x7 unroll
-        for dj in range(bw):
-            out = out + jnp.moveaxis(
-                contrib[di:di + ph, dj:dj + pw, :, di * bw + dj], 2, 0)
-    return out + b[:, None, None]
+        contrib = _score_contrib(flat, w, cfg, use_kernel, K * bh * bw)
+    with jax.named_scope("score/collate"):
+        contrib = contrib.reshape(BH, BW, K, bh * bw)
+        out = jnp.zeros((K, ph, pw), jnp.float32)
+        for di in range(bh):                            # static 15x7 unroll
+            for dj in range(bw):
+                out = out + jnp.moveaxis(
+                    contrib[di:di + ph, dj:dj + pw, :, di * bw + dj], 2, 0)
+        return out + b[:, None, None]
 
 
 @partial(jax.jit, static_argnames=("cfg", "backend"))
@@ -318,15 +322,16 @@ def nms_keep(boxes: Array, scores: Array, iou_thr: float) -> Array:
     host greedy reference (tests/test_stages_detector.py).
     """
     k = boxes.shape[0]
-    iou = matrix_iou(boxes, boxes)
-    valid = jnp.isfinite(scores)
-    rank = jnp.arange(k)
+    with jax.named_scope("nms"):
+        iou = matrix_iou(boxes, boxes)
+        valid = jnp.isfinite(scores)
+        rank = jnp.arange(k)
 
-    def body(i, keep):
-        suppressed = jnp.any(keep & (iou[:, i] > iou_thr) & (rank < i))
-        return keep.at[i].set(valid[i] & ~suppressed)
+        def body(i, keep):
+            suppressed = jnp.any(keep & (iou[:, i] > iou_thr) & (rank < i))
+            return keep.at[i].set(valid[i] & ~suppressed)
 
-    return jax.lax.fori_loop(0, k, body, jnp.zeros((k,), bool))
+        return jax.lax.fori_loop(0, k, body, jnp.zeros((k,), bool))
 
 
 def _nms(boxes: np.ndarray, scores: np.ndarray, iou_thr: float) -> List[int]:
@@ -494,39 +499,46 @@ def _frame_program(ph: int, pw: int, cfg: DetectorConfig) -> FrameProgram:
         multi = w.ndim == 2            # stacked (K, F) heads, static
         parts = []
         for sh, sw, _ in specs:
-            if (sh, sw) == (ph, pw):
-                g = gray
-            elif banded:
-                g = resize_banded(gray, sh, sw)
-            else:
-                wy, wx = resize_w[(sh, sw)]
-                g = (wy @ gray) @ wx.T
+            with jax.named_scope("resize"):
+                if (sh, sw) == (ph, pw):
+                    g = gray
+                elif banded:
+                    g = resize_banded(gray, sh, sw)
+                else:
+                    wy, wx = resize_w[(sh, sw)]
+                    g = (wy @ gray) @ wx.T
             sm = score_map(g, w, b, hcfg, cfg.backend)
             parts.append(sm.reshape(sm.shape[0], -1) if multi
                          else sm.reshape(-1))
-        scores = parts[0] if len(parts) == 1 \
-            else jnp.concatenate(parts, axis=-1)
-        # windows must lie inside the TRUE (unpadded) frame and clear
-        # the score threshold; both masks applied device-side
-        inside = (boxes_dev[:, 2] <= hw[0] + 1e-4) \
-            & (boxes_dev[:, 3] <= hw[1] + 1e-4)
         if multi:
             kh = int(w.shape[0])
             if cfg.class_thresholds and len(cfg.class_thresholds) != kh:
                 raise ValueError(
                     f"class_thresholds has {len(cfg.class_thresholds)} "
                     f"entries but the stacked params carry {kh} heads")
-            thr = jnp.asarray(cfg.class_thresholds
-                              or (cfg.score_threshold,) * kh, jnp.float32)
-            valid = inside[None, :] & (scores > thr[:, None])
+        with jax.named_scope("select"):
+            scores = parts[0] if len(parts) == 1 \
+                else jnp.concatenate(parts, axis=-1)
+            # windows must lie inside the TRUE (unpadded) frame and
+            # clear the score threshold; both masks applied device-side
+            inside = (boxes_dev[:, 2] <= hw[0] + 1e-4) \
+                & (boxes_dev[:, 3] <= hw[1] + 1e-4)
+            if multi:
+                thr = jnp.asarray(cfg.class_thresholds
+                                  or (cfg.score_threshold,) * kh,
+                                  jnp.float32)
+                valid = inside[None, :] & (scores > thr[:, None])
+            else:
+                valid = inside & (scores > cfg.score_threshold)
             top, idx = jax.lax.top_k(jnp.where(valid, scores, -jnp.inf), k)
+            cand = boxes_dev[idx]
+            n_valid = jnp.sum(valid, axis=-1)
+        if multi:
             keep = jax.vmap(nms_keep, in_axes=(0, 0, None))(
-                boxes_dev[idx], top, cfg.nms_iou)
-            return top, idx, keep, jnp.sum(valid, axis=-1)
-        valid = inside & (scores > cfg.score_threshold)
-        top, idx = jax.lax.top_k(jnp.where(valid, scores, -jnp.inf), k)
-        keep = nms_keep(boxes_dev[idx], top, cfg.nms_iou)
-        return top, idx, keep, jnp.sum(valid)
+                cand, top, cfg.nms_iou)
+        else:
+            keep = nms_keep(cand, top, cfg.nms_iou)
+        return top, idx, keep, n_valid
 
     return FrameProgram(jax.jit(fn), boxes_tab, scale_tab, n, k,
                         tuple(per_scale), fn,
@@ -539,10 +551,12 @@ def _prep_frame(frame: Array, h: int, w: int, ph: int, pw: int) -> Array:
     the jit so uint8 stays on the wire, XLA fuses the luma into the
     gradient stage, and the conversion happens once per frame -- every
     pyramid scale then resizes the one gray buffer."""
-    g = grayscale(frame) if frame.ndim == 3 else frame.astype(jnp.float32)
-    if (ph, pw) != (h, w):
-        g = jnp.pad(g, ((0, ph - h), (0, pw - w)), mode="edge")
-    return g
+    with jax.named_scope("gray"):
+        g = grayscale(frame) if frame.ndim == 3 \
+            else frame.astype(jnp.float32)
+        if (ph, pw) != (h, w):
+            g = jnp.pad(g, ((0, ph - h), (0, pw - w)), mode="edge")
+        return g
 
 
 @lru_cache(maxsize=64)
@@ -790,6 +804,7 @@ def _tile_local_fn(ph: int, pw: int, fp: int,
         off += sph * spw
     assert off == n, (off, n)
 
+    @jax.named_scope("select")
     def _finish(parts_s, parts_i, nv):
         s_all = parts_s[0] if len(parts_s) == 1 else jnp.concatenate(parts_s)
         i_all = parts_i[0] if len(parts_i) == 1 else jnp.concatenate(parts_i)
@@ -837,37 +852,41 @@ def _tile_local_fn(ph: int, pw: int, fp: int,
             for p in plans:
                 slab, hs, spw = p["slab"], p["hs"], p["spw"]
                 poff = d * (slab * cell)        # cell-aligned pixel base
-                if p["mode"] == "direct":
-                    g_ext = jnp.pad(gray, ((0, p["L"] - ph), (0, 0)))
-                    gs = jax.lax.dynamic_slice(g_ext, (poff, 0), (hs, pw))
-                elif p["mode"] == "banded":
-                    lo_loc = jax.lax.dynamic_slice(p["lo_r"], (poff,), (hs,))
-                    w_loc = jax.lax.dynamic_slice(
-                        p["w_r"], (poff, 0), (hs, p["w_r"].shape[1]))
-                    g_pad = jnp.pad(gray, ((0, p["w_r"].shape[1]), (0, 0)))
-                    gs = tiling.band_rows(g_pad, lo_loc, w_loc)
-                    if p["col"] is not None:
-                        lo_c, w_c = p["col"]
-                        gs = tiling.band_cols(
-                            jnp.pad(gs, ((0, 0), (0, w_c.shape[1]))),
-                            jnp.asarray(lo_c), jnp.asarray(w_c))
-                else:
-                    # matmul resize is NOT sliceable on its reduction
-                    # OR output rows pre-hoc: XLA picks GEMM blocking
-                    # (and with it the fp32 accumulation order) from
-                    # the operand shapes, so a (hs, ph) slice of wy can
-                    # produce different low bits than the same rows of
-                    # the full product. Run the untiled expression
-                    # verbatim and slice the RESULT -- data movement
-                    # only, bitwise by construction. Tiling then buys
-                    # no resize savings in this mode (the banded mode
-                    # is the performance path); it stays for parity.
-                    gs = p["wy"] @ gray
-                    if p["wx"] is not None:
-                        gs = gs @ p["wx"].T
-                    gs = jnp.pad(gs, ((0, p["L"] - p["sh"]), (0, 0)))
-                    gs = jax.lax.dynamic_slice(
-                        gs, (poff, 0), (hs, gs.shape[1]))
+                with jax.named_scope("resize"):
+                    if p["mode"] == "direct":
+                        g_ext = jnp.pad(gray, ((0, p["L"] - ph), (0, 0)))
+                        gs = jax.lax.dynamic_slice(g_ext, (poff, 0),
+                                                   (hs, pw))
+                    elif p["mode"] == "banded":
+                        lo_loc = jax.lax.dynamic_slice(p["lo_r"], (poff,),
+                                                       (hs,))
+                        w_loc = jax.lax.dynamic_slice(
+                            p["w_r"], (poff, 0), (hs, p["w_r"].shape[1]))
+                        g_pad = jnp.pad(gray,
+                                        ((0, p["w_r"].shape[1]), (0, 0)))
+                        gs = tiling.band_rows(g_pad, lo_loc, w_loc)
+                        if p["col"] is not None:
+                            lo_c, w_c = p["col"]
+                            gs = tiling.band_cols(
+                                jnp.pad(gs, ((0, 0), (0, w_c.shape[1]))),
+                                jnp.asarray(lo_c), jnp.asarray(w_c))
+                    else:
+                        # matmul resize is NOT sliceable on its reduction
+                        # OR output rows pre-hoc: XLA picks GEMM blocking
+                        # (and with it the fp32 accumulation order) from
+                        # the operand shapes, so a (hs, ph) slice of wy can
+                        # produce different low bits than the same rows of
+                        # the full product. Run the untiled expression
+                        # verbatim and slice the RESULT -- data movement
+                        # only, bitwise by construction. Tiling then buys
+                        # no resize savings in this mode (the banded mode
+                        # is the performance path); it stays for parity.
+                        gs = p["wy"] @ gray
+                        if p["wx"] is not None:
+                            gs = gs @ p["wx"].T
+                        gs = jnp.pad(gs, ((0, p["L"] - p["sh"]), (0, 0)))
+                        gs = jax.lax.dynamic_slice(
+                            gs, (poff, 0), (hs, gs.shape[1]))
                 smap = score_map(gs, wv, bv, hcfg, cfg.backend)
                 rows = d * slab + jnp.arange(slab, dtype=jnp.int32)
                 idx = (p["base"] + rows[:, None] * spw
@@ -904,13 +923,14 @@ def _tile_local_fn(ph: int, pw: int, fp: int,
             nv = jnp.zeros((), jnp.int32)
             for sh, sw, s, sph, spw, base_i in gspecs:
                 # exact same per-scale expressions as the untiled fn
-                if (sh, sw) == (ph, pw):
-                    g = gray
-                elif banded:
-                    g = tiling.resize_banded(gray, sh, sw)
-                else:
-                    wy, wx = rw[(sh, sw)]
-                    g = (wy @ gray) @ wx.T
+                with jax.named_scope("resize"):
+                    if (sh, sw) == (ph, pw):
+                        g = gray
+                    elif banded:
+                        g = tiling.resize_banded(gray, sh, sw)
+                    else:
+                        wy, wx = rw[(sh, sw)]
+                        g = (wy @ gray) @ wx.T
                 flat = score_map(g, wv, bv, hcfg, cfg.backend).reshape(-1)
                 bx = boxes_dev[base_i:base_i + sph * spw]
                 inside = (bx[:, 2] <= hw[0] + 1e-4) \
@@ -937,8 +957,9 @@ def _tile_local_fn(ph: int, pw: int, fp: int,
     def local(gray: Array, wv: Array, bv: Array, hw: Array):
         d = jax.lax.axis_index("tile")
         s_all, i_all, nv = jax.lax.switch(d, branches, gray, wv, bv, hw)
-        top, pos = jax.lax.top_k(s_all, k)
-        return top, i_all[pos], nv
+        with jax.named_scope("select"):
+            top, pos = jax.lax.top_k(s_all, k)
+            return top, i_all[pos], nv
 
     return local
 
@@ -1040,8 +1061,6 @@ def _autotune_chunk(h: int, w: int, ph: int, pw: int, batch: int,
                     cfg: DetectorConfig, frame_shape: Tuple[int, ...],
                     frame_dtype, dp: int = 1, fp: int = 1,
                     heads: int = 0) -> int:
-    import time
-
     from repro.core import autotune_cache
     layout = f"{'rgb' if len(frame_shape) == 4 else 'gray'}-{frame_dtype}"
     # `heads` rides at the END of the key so the k[7]/k[8] mesh indices
@@ -1083,6 +1102,7 @@ def _autotune_chunk(h: int, w: int, ph: int, pw: int, batch: int,
         bv = jnp.float32(0.0)
     hw_b = jnp.tile(jnp.asarray([h, w], jnp.float32), (batch, 1))
     probe_ms = {}
+    t_probe = time.perf_counter()
     for c in candidates:
         c_cfg = dataclasses.replace(cfg, batch_chunk=c)
         if fp > 1:
@@ -1100,7 +1120,8 @@ def _autotune_chunk(h: int, w: int, ph: int, pw: int, batch: int,
         probe_ms[c] = best * 1e3
     chunk = min(probe_ms, key=probe_ms.get)
     _AUTOTUNE[key] = {"chunk": chunk, "probe_ms": probe_ms,
-                      "source": "probe"}
+                      "source": "probe",
+                      "probe_s": time.perf_counter() - t_probe}
     autotune_cache.store(dkey, chunk, probe_ms)
     return chunk
 
@@ -1119,8 +1140,135 @@ def autotune_report() -> dict:
     ",tile:f" suffix marks the 2-D frame-parallel schedule) so BENCH
     entries stay unambiguous about which device layout a schedule was
     probed on; "source" says whether the decision was probed live or
-    restored from the disk cache (core/autotune_cache.py)."""
+    restored from the disk cache (core/autotune_cache.py); a live
+    probe also records its wall time, compiles included ("probe_s")."""
     return {_autotune_key_str(k): dict(v) for k, v in _AUTOTUNE.items()}
+
+
+# ------------------------------------------- dispatched programs, loads
+# Every frame program the detector has dispatched, keyed by its jitted
+# function (one per program through the lru caches above), with its
+# bucket, batch size and the abstract arguments it was called with, so
+# that its compiled text can be read back (`op_scopes`); and what
+# readying each program cost, as `DetectionSession.warmup` measures it
+# (`ready_programs`, `program_loads`). Process-wide, like the program
+# caches they describe; `DetectionSession.clear_cache` empties both.
+
+_PROGRAMS: dict = {}
+_LOADS: List[dict] = []
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _dispatch(fn, args: tuple, bucket: Tuple[int, int], batch: int):
+    """Call a frame program (which only enqueues its work), noting it on
+    its first call."""
+    new = fn not in _PROGRAMS
+    if new:
+        avals = tuple(jax.ShapeDtypeStruct(
+            np.shape(a), jnp.result_type(a),
+            sharding=getattr(a, "sharding", None)) for a in args)
+    with spans.span("detect.dispatch"):
+        out = fn(*args)
+    if new:
+        _PROGRAMS[fn] = {"bucket": bucket, "batch": batch, "args": avals}
+    return out
+
+
+@contextlib.contextmanager
+def ready_programs():
+    """Time readying the frame programs first dispatched inside the
+    block: compiling them or loading them from JAX's persistent cache,
+    plus the first run if the block waits for it. Each new program is
+    recorded once in `program_loads()`: its bucket, batch size, the
+    block's seconds, whether it compiled or loaded (JAX's monitoring
+    events: a backend compile without a persistent-cache hit compiled),
+    and the seconds of any batch-schedule probe the block ran."""
+    seen, tuned = set(_PROGRAMS), set(_AUTOTUNE)
+    n = {"compiles": 0, "cache_hits": 0}
+
+    def on_duration(event, duration, **_):
+        if event == _COMPILE_EVENT:
+            n["compiles"] += 1
+
+    def on_event(event, **_):
+        if event == _CACHE_HIT_EVENT:
+            n["cache_hits"] += 1
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_event_listener(on_event)
+    t0 = time.perf_counter()
+    try:
+        yield
+    finally:
+        seconds = time.perf_counter() - t0
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+        jax.monitoring.unregister_event_listener(on_event)
+    probe_s = sum(v.get("probe_s", 0.0) for k, v in _AUTOTUNE.items()
+                  if k not in tuned)
+    source = "compiled" if n["compiles"] > n["cache_hits"] else \
+        "loaded" if n["cache_hits"] else "in memory"
+    for fn, p in list(_PROGRAMS.items()):
+        if fn not in seen:
+            _LOADS.append({"bucket": p["bucket"], "batch": p["batch"],
+                           "seconds": seconds, "source": source, **n,
+                           "probe_s": probe_s})
+
+
+def program_loads() -> List[dict]:
+    """What readying each program cost (`ready_programs`), in order."""
+    return [dict(r) for r in _LOADS]
+
+
+_HLO_LINE = re.compile(r"^\s*(?:ROOT\s+)?%?([^\s=]+)\s*=\s(.*)$")
+_HLO_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_HLO_CALLS = re.compile(r"calls=%?([^\s,}]+)")
+_HLO_OPERAND = re.compile(r"%([^\s,)}]+)")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([^\s(]+)\s*\(")
+
+
+def hlo_op_names(text: str) -> dict:
+    """HLO instruction name -> `op_name` in a compiled module's text.
+    An instruction the compiler left without metadata (a fusion it
+    built, a layout copy, a tuple access) takes the first `op_name`
+    inside the computation it calls, else its first operand's: called
+    computations and operands come before their users in the text."""
+    own, inner = {}, {}
+    comp = None
+    for line in text.splitlines():
+        m = _HLO_LINE.match(line)
+        if m is None:
+            c = _HLO_COMPUTATION.match(line)
+            if c is not None and line.rstrip().endswith("{"):
+                comp = c.group(1)
+            continue
+        name, rest = m.groups()
+        op = _HLO_OP_NAME.search(rest)
+        if op is not None:
+            found = op.group(1)
+        else:
+            called = _HLO_CALLS.search(rest)
+            found = inner.get(called.group(1)) if called else None
+            if found is None:
+                found = next((own[a] for a in _HLO_OPERAND.findall(rest)
+                              if a in own), None)
+        if found is not None:
+            own[name] = found
+            inner.setdefault(comp, found)
+    return own
+
+
+def op_scopes(batch: int) -> dict:
+    """HLO instruction name -> its `op_name` (the named-scope path, e.g.
+    `jit(fn)/.../resize/dot_general`) in the compiled text of every
+    frame program dispatched with `batch` frames (1: one frame).
+    Instruction names are unique within a program, not across them."""
+    out = {}
+    for fn, p in list(_PROGRAMS.items()):
+        if p["batch"] == batch:
+            out.update(hlo_op_names(
+                fn.lower(*p["args"]).compile().as_text()))
+    return out
 
 
 class FrameDetector:
@@ -1218,24 +1366,26 @@ class FrameDetector:
         decodes lazily on first host access (`.to_list()` et al.).
         Grayscale + pad run inside the program (one dispatch per frame,
         keyed on the true shape like the batch path), and the frame
-        buffer is donated on accelerators.
+        buffer is donated on accelerators. Spans: `detect.upload`, the
+        frame to the device; `detect.dispatch`, enqueueing the program.
         """
         from repro.api.results import Detections
-        _frame_hw(np.shape(image))
-        frame = jnp.asarray(image)
-        h, w = int(frame.shape[0]), int(frame.shape[1])
+        h, w = _frame_hw(np.shape(image))
         prog, ph, pw = self.program_for(h, w)
         if prog.fn is None:
             return Detections.empty(prog.tables, self.classes)
-        if _donate() and isinstance(image, jax.Array):
-            # the program donates its frame argument; a caller-owned
-            # device buffer must not be invalidated under them
-            frame = jnp.array(frame, copy=True)
         fp = self._tiled_for(ph, pw)
         fn = (_tiled_single_fn(h, w, ph, pw, fp, self.cfg) if fp > 1
               else _single_fn(h, w, ph, pw, self.cfg))
-        top, idx, keep, n_valid = fn(frame, self.svm["w"], self.svm["b"],
-                                     jnp.asarray([h, w], jnp.float32))
+        with spans.span("detect.upload"):
+            frame = jnp.asarray(image)
+            if _donate() and isinstance(image, jax.Array):
+                # the program donates its frame argument; a caller-owned
+                # device buffer must not be invalidated under them
+                frame = jnp.array(frame, copy=True)
+            hw = jnp.asarray([h, w], jnp.float32)
+        top, idx, keep, n_valid = _dispatch(
+            fn, (frame, self.svm["w"], self.svm["b"], hw), (ph, pw), 1)
         return Detections(top, idx, keep, n_valid, prog.tables,
                           classes=self.classes)
 
@@ -1259,6 +1409,8 @@ class FrameDetector:
         padded to a multiple of the data mesh size (masked zero frames,
         sliced off the result) and runs sharded, B/n_devices frames per
         device -- per-frame results byte-identical to data_parallel=1.
+        Spans: `detect.stack` (the host stack of a frame list),
+        `detect.upload`, `detect.dispatch`.
         """
         from repro.api.results import Detections
         if isinstance(frames, (list, tuple)) and not frames:
@@ -1269,8 +1421,9 @@ class FrameDetector:
         uniform = not isinstance(frames, (list, tuple)) or \
             len({np.shape(f) for f in frames}) == 1
         if uniform:
-            batch = np.stack([np.asarray(f) for f in frames]) \
-                if isinstance(frames, (list, tuple)) else frames
+            with spans.span("detect.stack"):
+                batch = np.stack([np.asarray(f) for f in frames]) \
+                    if isinstance(frames, (list, tuple)) else frames
             shape = tuple(np.shape(batch))
             if not isinstance(frames, (list, tuple)) \
                     and len(shape) == 3 and shape[-1] == 3:
@@ -1307,22 +1460,32 @@ class FrameDetector:
             return Detections.empty_batch(prog.tables, n,
                                           self.classes)
         th, tw = (h, w) if uniform else (ph, pw)
-        if uniform:
-            frames_b = jnp.asarray(batch)
-        else:
-            frames_b = jnp.stack([self._pad_to(g, ph, pw) for g in grays])
         cfg = self.cfg
         dp = _resolve_dp(cfg)
         n_pad = _round_up(n, dp) if dp > 1 else n
-        if n_pad != n:
-            # pad the batch up to the mesh's data size with zero frames
-            # whose true-size mask is (0, 0): every window fails the
-            # inside-frame test, so pad rows decode to empty results
-            # and are sliced off below before the Detections is built
-            pad = jnp.zeros((n_pad - n,) + tuple(frames_b.shape[1:]),
-                            frames_b.dtype)
-            frames_b = jnp.concatenate([frames_b, pad])
-            hws = list(hws) + [(0, 0)] * (n_pad - n)
+        with spans.span("detect.upload"):
+            if uniform:
+                frames_b = jnp.asarray(batch)
+            else:
+                frames_b = jnp.stack([self._pad_to(g, ph, pw)
+                                      for g in grays])
+            if n_pad != n:
+                # pad the batch up to the mesh's data size with zero
+                # frames whose true-size mask is (0, 0): every window
+                # fails the inside-frame test, so pad rows decode to
+                # empty results and are sliced off below before the
+                # Detections is built
+                pad = jnp.zeros((n_pad - n,) + tuple(frames_b.shape[1:]),
+                                frames_b.dtype)
+                frames_b = jnp.concatenate([frames_b, pad])
+                hws = list(hws) + [(0, 0)] * (n_pad - n)
+            elif _donate() and isinstance(frames, jax.Array):
+                # the batched program donates its frame stack; only
+                # copy when the caller handed us their own device buffer
+                # (lists, numpy stacks and the pad concatenate above all
+                # produced a fresh one already)
+                frames_b = jnp.array(frames_b, copy=True)
+            hw_b = jnp.asarray(hws, jnp.float32)
         fp = self._tiled_for(ph, pw, dp)
         if cfg.batch_chunk == 0:         # autotune scan-vs-vmap (first use)
             chunk = _autotune_chunk(th, tw, ph, pw, n_pad, cfg,
@@ -1336,15 +1499,9 @@ class FrameDetector:
             fn = _sharded_batch_fn(th, tw, ph, pw, n_pad, dp, cfg, _donate())
         else:
             fn = _batch_fn(th, tw, ph, pw, n_pad, cfg, _donate())
-        if _donate() and n_pad == n and isinstance(frames, jax.Array):
-            # the batched program donates its frame stack; only copy
-            # when the caller handed us their own device buffer (lists,
-            # numpy stacks and the pad concatenate above all produced a
-            # fresh one already)
-            frames_b = jnp.array(frames_b, copy=True)
-        hw_b = jnp.asarray(hws, jnp.float32)
-        top, idx, keep, n_valid = fn(frames_b, self.svm["w"],
-                                     self.svm["b"], hw_b)
+        top, idx, keep, n_valid = _dispatch(
+            fn, (frames_b, self.svm["w"], self.svm["b"], hw_b), (ph, pw),
+            n_pad)
         if n_pad != n:                   # drop the masked pad rows
             top, idx, keep, n_valid = (top[:n], idx[:n], keep[:n],
                                        n_valid[:n])

@@ -22,8 +22,9 @@ stamped by the `Emitter` plus kind-specific payload:
     deadline_shed    n shed, queue_depth, deadline_ms
     worker_failure   error, transient, retries_left, breaker state
     restart          restarts total, breaker state
-    service_stop     final counter totals (frames, sheds, restarts, ...)
-    stage_timing     per-stage ms from the session timing hook
+    service_stop     final counter totals (frames, sheds, restarts, ...),
+                     and per span name count, total ms and self ms when
+                     spans were recorded (obs/spans.py)
 
 Sinks: `JsonlSink` (one JSON object per line -- `tail -f`-able and
 re-parseable, the round-trip contract tests/test_metrics.py pins),
@@ -44,7 +45,7 @@ import json
 import threading
 import time
 from collections import Counter, deque
-from dataclasses import dataclass, field
+from dataclasses import InitVar, dataclass, field
 from typing import (Any, Callable, Dict, Iterable, List, Optional,
                     Protocol, Tuple, runtime_checkable)
 
@@ -185,14 +186,25 @@ class MetricsConfig:
                  surfaced as `stats()["metrics"]["recent"]` counts
     rank0_only   only emit from `platform.is_main()` (default True --
                  the multi-host single-writer guard)
-    stage_timing forward the session's per-stage timing dict as
-                 `stage_timing` events (off by default: it's verbose)
+    spans        record in-memory spans (obs/spans.py) while the service
+                 runs, up to this many (0 = off)
+
+    `stage_timing=True` is the older spelling of spans on, at
+    `DEFAULT_SPANS`; it is accepted so that callers written for the
+    per-batch stage events it used to switch keep working.
     """
+
+    DEFAULT_SPANS = 1 << 18
 
     jsonl_path: str = ""
     ring: int = 0
     rank0_only: bool = True
-    stage_timing: bool = False
+    spans: int = 0
+    stage_timing: InitVar[bool] = False
+
+    def __post_init__(self, stage_timing: bool) -> None:
+        if stage_timing and not self.spans:
+            object.__setattr__(self, "spans", self.DEFAULT_SPANS)
 
     @property
     def enabled(self) -> bool:

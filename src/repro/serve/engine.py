@@ -83,6 +83,7 @@ from repro.core.pipeline import classify_windows
 from repro.core.svm import SVMParams
 from repro.models.configs import ModelConfig
 from repro.models.model import decode_step, prefill
+from repro.obs import spans
 from repro.obs.metrics import Emitter, MetricsConfig, make_sink
 from repro.serve.faults import DETERMINISTIC_TYPES, FaultInjector
 from repro.serve.resilience import (CircuitBreaker, DegradationLadder,
@@ -107,6 +108,8 @@ class FrameRequest:
     t_submit: float = 0.0               # for sojourn-latency telemetry
     attempts: int = 0                   # serve attempts consumed so far
     answered: bool = False              # exactly-once answer guard
+    span_id: int = 0                    # serve.request span (spans on)
+    span_t0: float = 0.0                # its perf_counter start
 
 
 class ServiceOverloaded(RuntimeError):
@@ -263,6 +266,7 @@ class DetectionService:
                if self._metrics_ring is not None else {})}
 
     def start(self):
+        spans.RECORDER.start(self.metrics.spans)
         self._supervisor.start()
         self._emit.emit(
             "service_start",
@@ -291,6 +295,10 @@ class DetectionService:
         # requests still pending (worker never started, died, or the
         # join timed out mid-batch) would otherwise hang their clients
         self._drain_pending("DetectionService stopped with a backlog")
+        span_totals = {}
+        if self.metrics.spans:
+            spans.RECORDER.stop()
+            span_totals = spans.totals(spans.RECORDER.spans())
         self._emit.emit(
             "service_stop",
             frames=self.stats["frames"], batches=self.stats["frame_batches"],
@@ -301,7 +309,8 @@ class DetectionService:
             worker_failures=self.stats["worker_failures"],
             frames_degraded=self.stats["frames_degraded"],
             latency_ms=self.stats["latency_ms"],
-            ladder=self.stats["ladder"], breaker=self.stats["breaker"])
+            ladder=self.stats["ladder"], breaker=self.stats["breaker"],
+            **({"spans": span_totals} if span_totals else {}))
         self._metrics_stats()
         self._emit.close()
 
@@ -386,6 +395,7 @@ class DetectionService:
         `DeadlineExceeded` payload. Raises `ServiceStopped` after
         stop(), `CircuitOpen` while the breaker fails fast, and
         `ServiceOverloaded` when the pending bound is hit."""
+        t_span = time.perf_counter() if spans.RECORDER.on else 0.0
         self._check_admission()
         fut: "queue.Queue" = queue.Queue(maxsize=1)
         dl = deadline_ms if deadline_ms is not None \
@@ -393,6 +403,8 @@ class DetectionService:
         now = time.monotonic()
         req = FrameRequest(frame, fut, t_submit=now,
                            deadline=None if not dl else now + dl / 1e3)
+        if t_span:
+            req.span_id, req.span_t0 = spans.RECORDER.new_id(), t_span
         # the bound counts every accepted-but-unanswered request --
         # queued, parked in the bucket backlog, or in the worker's
         # hands -- so shuffling between holding areas cannot grow total
@@ -430,6 +442,10 @@ class DetectionService:
                 return False
             req.answered = True
             self._pending_frames -= 1
+        if req.span_id:
+            spans.RECORDER.add("serve.request", req.span_t0,
+                               time.perf_counter(), request=req.span_id,
+                               batch=spans.RECORDER.current_batch())
         self.stats["frame_answers"] += 1
         if "error" in payload:
             self.stats["frame_errors"] += 1
@@ -634,6 +650,12 @@ class DetectionService:
         compute. The fault hook and the batch dispatch run OUTSIDE the
         per-batch containment on purpose: an escape there is a worker
         failure the supervisor handles (retry / fail-fast / restart).
+
+        With spans on (obs/spans.py), `serve.batch` runs from the first
+        request's pop to the last answer, with the children
+        `serve.gather` (straggler wait and bucketing), `serve.run` (the
+        detector call), `serve.decode` (per-frame slice and decode) and
+        `serve.answer`.
         """
         req = None
         while req is None:
@@ -642,12 +664,22 @@ class DetectionService:
                 return False
             if self._shed_expired(req):
                 req = None
+        with spans.span("serve.batch", batch=True):
+            with spans.span("serve.gather"):
+                group = self._gather(req)
+            if group:
+                self._serve_group(group)
+        return True
+
+    def _gather(self, req: FrameRequest) -> List[FrameRequest]:
+        """The batch `req` opens: same-bucket requests until the frame
+        target or the straggler wait, less those shed."""
         try:
             bucket = self._detector.bucket_for(req.frame)
         except Exception as e:
             self._answer_frame(req, {"detections": [], "ms": 0.0,
                                      "error": f"{type(e).__name__}: {e}"})
-            return True
+            return []
         group: List[FrameRequest] = [req]
         parked: List[FrameRequest] = []
         deadline = time.monotonic() + self.max_wait
@@ -680,10 +712,10 @@ class DetectionService:
 
         # last shed pass: the straggler wait may have burned the budget
         now = time.monotonic()
-        group = [r for r in group if not self._shed_expired(r, now)]
-        if not group:
-            return True
+        return [r for r in group if not self._shed_expired(r, now)]
 
+    def _serve_group(self, group: List[FrameRequest]) -> None:
+        """Run one gathered batch and answer each of its requests."""
         rung = self._ladder.rung
         self._inflight = group
         if self.faults is not None:
@@ -691,20 +723,22 @@ class DetectionService:
             # worker failure / device loss / thread kill)
             self.faults.before_batch(len(group))
 
-        t_dispatch = time.monotonic()
         t0 = time.perf_counter()
         if rung == "full":
             try:
-                if len(group) == 1:
-                    results = [self._detector.detect_raw(group[0].frame)]
-                else:
-                    batch = self._detector.detect_batch_raw(
-                        [r.frame for r in group])
-                    results = [batch.frame(i) for i in range(len(group))]
+                with spans.span("serve.run"):
+                    if len(group) == 1:
+                        results = [self._detector.detect_raw(group[0].frame)]
+                    else:
+                        batch = self._detector.detect_batch_raw(
+                            [r.frame for r in group])
                 # decode inside the timed region so per-frame ms keeps
                 # the legacy meaning (device step + host decode)
-                dets_per = [(res.to_list(), bool(np.any(res.saturated)))
-                            for res in results]
+                with spans.span("serve.decode"):
+                    if len(group) > 1:
+                        results = [batch.frame(i) for i in range(len(group))]
+                    dets_per = [(res.to_list(), bool(np.any(res.saturated)))
+                                for res in results]
             except Exception:
                 # batch failed as a whole: fall back to per-frame so one
                 # poisonous frame cannot fail its innocent batch-mates;
@@ -712,48 +746,51 @@ class DetectionService:
                 # run on the device is visible, not silently replaced
                 self.stats["batch_fallbacks"] += 1
                 dets_per = []
-                for r in group:
-                    try:
-                        res = self._detector.detect_raw(r.frame)
-                        dets_per.append((res.to_list(),
-                                         bool(np.any(res.saturated))))
-                    except Exception as e:
-                        dets_per.append(e)
+                with spans.span("serve.run"):
+                    for r in group:
+                        try:
+                            res = self._detector.detect_raw(r.frame)
+                            dets_per.append((res.to_list(),
+                                             bool(np.any(res.saturated))))
+                        except Exception as e:
+                            dets_per.append(e)
         else:
             # degraded rung: per-frame through the cheap entry point
             dets_per = []
-            for r in group:
-                try:
-                    dets_per.append(self._degraded_result(rung, r.frame))
-                except Exception as e:
-                    dets_per.append(e)
+            with spans.span("serve.run"):
+                for r in group:
+                    try:
+                        dets_per.append(self._degraded_result(rung, r.frame))
+                    except Exception as e:
+                        dets_per.append(e)
         ms = (time.perf_counter() - t0) * 1e3 / len(group)
         self.stats["frame_batches"] += 1
         self._account_device_frames(len(group))
         now = time.monotonic()
-        for r, dets in zip(group, dets_per):
-            if isinstance(dets, Exception):
-                self._answer_frame(
-                    r, {"detections": [], "ms": 0.0,
-                        "degraded_mode": rung,
-                        "error": f"{type(dets).__name__}: {dets}"})
-                continue
-            dets, saturated = dets
-            self.stats["frames"] += 1
-            if rung != "full":
-                self.stats["frames_degraded"] += 1
-            self.stats["frames_saturated"] += int(saturated)
-            self.stats["frame_boxes"] += len(dets)
-            for d in dets:                       # per-class serve stats
-                if "label" in d:
-                    cb = self.stats["class_boxes"]
-                    cb[d["label"]] = cb.get(d["label"], 0) + 1
-            self.stats["frame_ms"] += (ms - self.stats["frame_ms"]) \
-                / self.stats["frames"]
-            self._latency.add((now - r.t_submit) * 1e3)
-            self._answer_frame(r, {"detections": dets, "ms": ms,
-                                   "saturated": saturated,
-                                   "degraded_mode": rung})
+        with spans.span("serve.answer"):
+            for r, dets in zip(group, dets_per):
+                if isinstance(dets, Exception):
+                    self._answer_frame(
+                        r, {"detections": [], "ms": 0.0,
+                            "degraded_mode": rung,
+                            "error": f"{type(dets).__name__}: {dets}"})
+                    continue
+                dets, saturated = dets
+                self.stats["frames"] += 1
+                if rung != "full":
+                    self.stats["frames_degraded"] += 1
+                self.stats["frames_saturated"] += int(saturated)
+                self.stats["frame_boxes"] += len(dets)
+                for d in dets:                       # per-class serve stats
+                    if "label" in d:
+                        cb = self.stats["class_boxes"]
+                        cb[d["label"]] = cb.get(d["label"], 0) + 1
+                self.stats["frame_ms"] += (ms - self.stats["frame_ms"]) \
+                    / self.stats["frames"]
+                self._latency.add((now - r.t_submit) * 1e3)
+                self._answer_frame(r, {"detections": dets, "ms": ms,
+                                       "saturated": saturated,
+                                       "degraded_mode": rung})
         self._inflight = []
         self.stats["frame_occupancy"] = (
             self.stats["frames"]
@@ -789,15 +826,7 @@ class DetectionService:
                     direction="degrade" if self._rung_level(
                         self._ladder.rung) > self._rung_level(rung)
                     else "recover")
-            if self.metrics.stage_timing:
-                queue_ms = [(t_dispatch - r.t_submit) * 1e3 for r in group]
-                self._emit.emit(
-                    "stage_timing", n=len(group),
-                    queue_ms_mean=round(sum(queue_ms) / len(queue_ms), 3),
-                    queue_ms_max=round(max(queue_ms), 3),
-                    compute_ms_per_frame=round(ms, 3))
             self._metrics_stats()
-        return True
 
     def _rung_level(self, rung: str) -> int:
         """Index of a rung in the ladder (higher = more degraded)."""

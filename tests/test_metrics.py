@@ -1,4 +1,5 @@
-"""Metrics export suite (DESIGN.md §15, `repro.obs.metrics`).
+"""Metrics export and span suite (DESIGN.md §15, `repro.obs.metrics`,
+`repro.obs.spans`).
 
 Two families, mirroring test_resilience.py:
 
@@ -15,15 +16,19 @@ Two families, mirroring test_resilience.py:
 Chaos fixtures reuse test_resilience.py's tiny-frame setup (160x128,
 single scale, threshold -10) so no new programs compile.
 """
+import dataclasses
 import json
+import re
 import threading
 import time
 
-import numpy as np
+import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core.detector import DetectorConfig
+from repro.obs import spans as obs_spans
 from repro.obs.metrics import (CallbackSink, Emitter, JsonlSink,
                                MetricsConfig, MetricsSink, NullSink,
                                RingSink, TeeSink, make_sink)
@@ -39,7 +44,7 @@ DET_CFG = DetectorConfig(score_threshold=-10.0, scales=(1.0,))
 #: every event kind the engine can emit (metrics.py module docstring)
 KNOWN_KINDS = {"service_start", "batch", "rung_transition",
                "deadline_shed", "worker_failure", "restart",
-               "service_stop", "stage_timing"}
+               "service_stop"}
 
 
 def _frames(n, h=160, w=128, seed=11):
@@ -322,7 +327,26 @@ def test_chaos_run_emits_transition_restart_and_shed(tmp_path):
     assert stop["restarts"] >= 1 and stop["deadline_shed"] >= 2
 
 
-def test_stage_timing_events_opt_in(tmp_path):
+@pytest.fixture
+def recorder():
+    """The process's span recorder, off and empty before and after."""
+    rec = obs_spans.RECORDER
+    rec.stop()
+    rec.drain()
+    yield rec
+    rec.stop()
+    rec.drain()
+
+
+def test_stage_timing_events_opt_in(tmp_path, recorder):
+    """Stage timing is opt-in, as in-memory spans: `stage_timing=True`
+    (the older spelling) switches them on at the default capacity, the
+    service records them while it runs and stops at `stop()`, and the
+    `service_stop` event carries each span name's count, total and self
+    ms. Nothing per stage goes to the sink while serving."""
+    assert MetricsConfig(stage_timing=True).spans == \
+        MetricsConfig.DEFAULT_SPANS
+    assert MetricsConfig(spans=5, stage_timing=True).spans == 5
     path = str(tmp_path / "stage.jsonl")
     svc = _service(metrics=MetricsConfig(jsonl_path=path,
                                          stage_timing=True))
@@ -331,9 +355,275 @@ def test_stage_timing_events_opt_in(tmp_path):
         svc.detect_frames(_frames(3), timeout=120)
     finally:
         svc.stop()
-    stages = [e for e in JsonlSink.read(path)
-              if e["kind"] == "stage_timing"]
-    assert stages, "stage_timing=True emitted no stage events"
-    for e in stages:
-        assert e["queue_ms_mean"] >= 0
-        assert e["compute_ms_per_frame"] > 0
+    assert not recorder.on
+    events = JsonlSink.read(path)
+    assert {e["kind"] for e in events} <= KNOWN_KINDS
+    (stop,) = [e for e in events if e["kind"] == "service_stop"]
+    got = stop["spans"]
+    assert got["serve.request"]["count"] == 3
+    for name in ("serve.batch", "serve.gather", "serve.run",
+                 "detect.dispatch", "serve.decode", "serve.answer"):
+        assert got[name]["count"] >= 1, name
+        assert 0 <= got[name]["self_ms"] <= got[name]["total_ms"] + 1e-6
+    assert got["serve.batch"]["self_ms"] < got["serve.batch"]["total_ms"]
+
+
+def test_spans_off_by_default_record_nothing(recorder):
+    """Off: one shared no-op context, nothing kept, no hook installed."""
+    import gc
+    assert MetricsConfig().spans == 0
+    a, b = recorder.span("x"), recorder.span("y", batch=True)
+    assert a is b
+    with a:
+        recorder.add("z", 0.0, 1.0)
+    gc.collect()
+    assert recorder.spans() == [] and recorder.dropped == 0
+    assert recorder._on_gc not in gc.callbacks
+    svc = _service()
+    svc.start()
+    try:
+        svc.detect_frames(_frames(2), timeout=120)
+    finally:
+        svc.stop()
+    assert recorder.spans() == []
+
+
+def test_spans_nest_by_thread_with_batch_and_request_ids():
+    """Each thread keeps its own stack: a child's parent is the span
+    open on its thread, it inherits that span's batch and request, and
+    a span added from elsewhere keeps the ids it is given."""
+    rec = obs_spans.SpanRecorder()
+    rec.start(1000)
+    barrier = threading.Barrier(2)
+
+    def work(tag):
+        with rec.span(f"{tag}.batch", batch=True):
+            barrier.wait(timeout=10)
+            with rec.span(f"{tag}.run"):
+                with rec.span(f"{tag}.leaf"):
+                    barrier.wait(timeout=10)
+        with rec.span(f"{tag}.req", request=7 if tag == "a" else 8):
+            with rec.span(f"{tag}.inner"):
+                pass
+
+    try:
+        ts = [threading.Thread(target=work, args=(t,)) for t in "ab"]
+        [t.start() for t in ts]
+        [t.join(timeout=30) for t in ts]
+        assert not any(t.is_alive() for t in ts)
+        rec.add("x.request", 1.0, 2.0, request=99, batch=3)
+    finally:
+        rec.stop()
+    by = {s.name: s for s in rec.spans()}
+    for tag, req in (("a", 7), ("b", 8)):
+        batch, run, leaf = (by[f"{tag}.{n}"] for n in ("batch", "run",
+                                                        "leaf"))
+        assert batch.parent is None and batch.batch == batch.id
+        assert run.parent == batch.id and run.batch == batch.id
+        assert leaf.parent == run.id and leaf.batch == batch.id
+        assert batch.start <= run.start <= leaf.start <= leaf.end \
+            <= run.end <= batch.end
+        assert by[f"{tag}.req"].parent is None
+        assert by[f"{tag}.req"].batch is None
+        assert by[f"{tag}.inner"].request == req
+        assert by[f"{tag}.inner"].parent == by[f"{tag}.req"].id
+    assert by["a.batch"].batch != by["b.batch"].batch
+    x = by["x.request"]
+    assert (x.request, x.batch, x.parent) == (99, 3, None)
+    assert len({s.id for s in rec.spans()}) == len(rec.spans())
+
+
+def test_spans_past_capacity_are_counted_as_dropped():
+    rec = obs_spans.SpanRecorder()
+    rec.start(3)
+    try:
+        for i in range(5):
+            with rec.span(f"s{i}"):
+                pass
+    finally:
+        rec.stop()
+    kept, dropped = rec.drain()
+    assert [s.name for s in kept] == ["s0", "s1", "s2"] and dropped == 2
+    assert rec.drain() == ([], 0)
+
+
+def test_span_totals_split_self_from_children_time():
+    S = obs_spans.Span
+    got = obs_spans.totals([S("leaf", 1.0, 2.0, 3, 2, None, 1),
+                            S("run", 0.5, 2.5, 2, 1, None, 1),
+                            S("batch", 0.0, 3.0, 1, None, None, 1),
+                            S("leaf", 2.5, 2.75, 4, 1, None, 1)])
+    assert got["batch"]["count"] == 1
+    assert got["batch"]["total_ms"] == pytest.approx(3000.0)
+    assert got["batch"]["self_ms"] == pytest.approx(750.0)
+    assert got["run"]["self_ms"] == pytest.approx(1000.0)
+    assert got["leaf"] == {"count": 2, "total_ms": pytest.approx(1250.0),
+                           "self_ms": pytest.approx(1250.0)}
+
+
+def test_gc_and_compile_spans_nest_under_the_open_span():
+    """`host.gc` from the gc hook and `jax.compile` from JAX's compile
+    event are recorded as children of the span open on the thread,
+    while the recorder is on; off, both hooks are gone."""
+    import gc
+    rec = obs_spans.SpanRecorder()
+    rec.start(100)
+    try:
+        with rec.span("stage", batch=True):
+            gc.collect()
+            jax.jit(lambda x: x * 3 + 1)(jnp.arange(7.0)).block_until_ready()
+    finally:
+        rec.stop()
+    assert rec._on_gc not in gc.callbacks
+    by = {}
+    for s in rec.spans():
+        by.setdefault(s.name, []).append(s)
+    (stage,) = by["stage"]
+    for name in ("host.gc", "jax.compile"):
+        assert by.get(name), name
+        for s in by[name]:
+            assert s.parent == stage.id and s.batch == stage.batch
+            assert stage.start - 1e-3 <= s.start <= s.end <= stage.end
+
+
+def test_served_batch_yields_nested_service_and_detector_spans(recorder):
+    """A served batch on the CPU: `serve.batch` holds `serve.gather`,
+    `serve.run` (holding `detect.stack`, `detect.upload`,
+    `detect.dispatch`), `serve.decode` (holding `detect.slice`,
+    `detect.fetch`, `detect.decode`) and `serve.answer`; each request's
+    `serve.request` names its own request and the batch that answered
+    it."""
+    svc = _service(frame_batch=3, max_wait_ms=2000.0,
+                   metrics=MetricsConfig(spans=10_000))
+    frames = _frames(3)
+    svc.start()
+    try:
+        # the straggler wait (2 s) gathers all three into one batch
+        futs = [svc.submit_frame(f) for f in frames]
+        for f in futs:
+            assert "error" not in f.get(timeout=120)
+    finally:
+        svc.stop()
+    got = recorder.spans()
+    by_id = {s.id: s for s in got}
+    (batch,) = [s for s in got if s.name == "serve.batch"]
+    kids = {s.name: s for s in got if s.parent == batch.id}
+    assert set(kids) >= {"serve.gather", "serve.run", "serve.decode",
+                         "serve.answer"}
+    for name, parent in (("detect.stack", "serve.run"),
+                         ("detect.upload", "serve.run"),
+                         ("detect.dispatch", "serve.run"),
+                         ("detect.slice", "serve.decode"),
+                         ("detect.fetch", "serve.decode"),
+                         ("detect.decode", "serve.decode")):
+        mine = [s for s in got if s.name == name]
+        assert mine, name
+        for s in mine:
+            assert by_id[s.parent].name == parent, name
+            assert s.batch == batch.id
+    assert len([s for s in got if s.name == "detect.slice"]) == 3
+    for s in got:
+        if s.batch == batch.id and s.name != "serve.request":
+            assert batch.start <= s.start <= s.end <= batch.end, s.name
+    reqs = [s for s in got if s.name == "serve.request"]
+    assert len(reqs) == 3 and len({s.request for s in reqs}) == 3
+    assert all(s.batch == batch.id and s.start < batch.start for s in reqs)
+
+
+# ====================================== frame program scopes and loads
+
+SCOPE_CFG = DetectorConfig(score_threshold=-10.0, scales=(1.0, 0.8),
+                           batch_chunk=2)
+
+
+@pytest.fixture(scope="module")
+def lowered_programs():
+    """The lowered text of the single-frame and the batched program at a
+    frame size where both pyramid levels hold a window."""
+    from repro.core.detector import _batch_fn, _single_fn
+    h, w = 200, 100
+    fr = jax.ShapeDtypeStruct((h, w, 3), jnp.uint8)
+    wv = jax.ShapeDtypeStruct((3780,), jnp.float32)
+    bv = jax.ShapeDtypeStruct((), jnp.float32)
+    single = _single_fn(h, w, h, w, SCOPE_CFG).lower(
+        fr, wv, bv, jax.ShapeDtypeStruct((2,), jnp.float32))
+    batched = _batch_fn(h, w, h, w, 4, SCOPE_CFG).lower(
+        jax.ShapeDtypeStruct((4, h, w, 3), jnp.uint8), wv, bv,
+        jax.ShapeDtypeStruct((4, 2), jnp.float32))
+    names = {}
+    for k, low in (("single", single), ("batched", batched)):
+        # scope paths of the ops, transform wrappers (vmap(...)) removed
+        names[k] = {re.sub(r"[\w.]+\(|\)", "", n) for n in re.findall(
+            r'loc\("([^"]*)"', low.as_text(debug_info=True))}
+    return names
+
+
+@pytest.mark.parametrize("program", ["single", "batched"])
+@pytest.mark.parametrize("scope", ["gray", "resize", "hog", "score/matmul",
+                                   "score/collate", "select", "nms"])
+def test_each_stage_scope_is_in_the_lowered_frame_program(
+        lowered_programs, program, scope):
+    """Every stage of the frame program carries its named scope into
+    the program's metadata (the device trace's `op_name`); under the
+    batch's vmap a path reads `vmap(<scope>)/...`."""
+    rx = re.compile(rf"(^|/){re.escape(scope)}(/|$)")
+    assert any(rx.search(n) for n in lowered_programs[program]), scope
+
+
+def test_program_loads_count_each_bucket_and_batch_once():
+    """warmup records what readying each (bucket, B) program cost, once:
+    a shape warmed again, or a second session on the same programs,
+    adds nothing; `op_scopes` reads the stages back from each
+    dispatched program's compiled text."""
+    from repro.api import DetectionSession, PipelineConfig
+    from repro.core import detector
+    cfg = PipelineConfig()
+    cfg = cfg.replace(detector=dataclasses.replace(
+        SCOPE_CFG, score_threshold=-7.5))
+    session = DetectionSession(SVM, cfg)
+    session.clear_cache()
+    stats = session.warmup([(200, 100), (2, 200, 100), (200, 100)])
+    loads = stats["program_loads"]
+    bucket = session.detector.bucket_for(np.zeros((200, 100, 3)))
+    assert [(r["bucket"], r["batch"]) for r in loads] == \
+        [(bucket, 1), (bucket, 2)]
+    for r in loads:
+        assert r["seconds"] > 0 and r["source"] in (
+            "compiled", "loaded", "in memory")
+        assert r["compiles"] >= 0 and r["cache_hits"] >= 0
+        assert r["probe_s"] == 0
+    DetectionSession(SVM, cfg).warmup([(2, 200, 100)])
+    assert detector.program_loads() == loads
+    for batch in (1, 2):
+        scopes = detector.op_scopes(batch)
+        assert scopes and any("/nms/" in v or "(nms)" in v
+                              for v in scopes.values())
+    session.clear_cache()
+    assert detector.program_loads() == []
+
+
+def test_hlo_op_names_fill_what_the_compiler_left_without_metadata():
+    """A fusion the compiler built takes an op_name from the computation
+    it calls; a layout copy or tuple access takes its operand's."""
+    from repro.core.detector import hlo_op_names
+    text = """HloModule jit_fn
+
+%fused_computation.1 (param_0: f32[4]) -> f32[4] {
+  %param_0 = f32[4]{0} parameter(0)
+  ROOT %add.1 = f32[4]{0} add(%param_0, %param_0), metadata={op_name="jit(fn)/vmap(nms)/while/body/add"}
+}
+
+ENTRY %main.9 (Arg_0.1: f32[4]) -> f32[4] {
+  %Arg_0.1 = f32[4]{0} parameter(0), metadata={op_name="frame"}
+  %fusion.7 = f32[4]{0:T(8,128)} fusion(%Arg_0.1), kind=kLoop, calls=%fused_computation.1
+  %custom-call.3 = (f32[4]{0}, f32[4]{0}) custom-call(%fusion.7), custom_call_target="k", metadata={op_name="jit(fn)/hog/jit(dense_fused_hog)"}
+  %get-tuple-element.5 = f32[4]{0} get-tuple-element(%custom-call.3), index=0
+  ROOT %copy.2 = f32[4]{0:T(8,128)} copy(%get-tuple-element.5)
+}
+"""
+    got = hlo_op_names(text)
+    nms = "jit(fn)/vmap(nms)/while/body/add"
+    hog = "jit(fn)/hog/jit(dense_fused_hog)"
+    assert got == {"add.1": nms, "Arg_0.1": "frame", "fusion.7": nms,
+                   "custom-call.3": hog, "get-tuple-element.5": hog,
+                   "copy.2": hog}
