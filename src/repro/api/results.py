@@ -12,14 +12,16 @@ result type of the api layer:
   * is a registered jax pytree, so batched results ride through
     jit/vmap/scan untouched,
   * decodes LAZILY: nothing syncs to host until `.to_list()` /
-    `.boxes` / `len()` is called, and the decode is cached,
+    `.boxes` / `len()` / `.frame(i)` is called, and the decode is
+    cached,
   * `.to_list()` reproduces the legacy dict contract byte for byte
     (`{"box": (y0, x0, y1, x1), "score", "scale"}`, descending score),
   * `.saturated` answers programmatically what used to be only a
     RuntimeWarning: did more candidates clear the threshold than the
     program's top-k could hold? (per-frame bool array on batches),
   * a leading batch axis makes a batch-of-frames result: `d.frame(i)`
-    slices one frame out, `Detections.stack([...])` goes the other way,
+    slices one frame out of the batch's one host copy (`fetch()`, made
+    on first use), `Detections.stack([...])` goes the other way,
   * `Detections.from_list(dicts)` wraps already-host results (the
     tracking path) so `stream()` returns the same type; extra keys such
     as `track_id` pass through `.to_list()` unchanged (they do not
@@ -63,6 +65,11 @@ class Detections:
         self._tables = tables          # static: .boxes (N,4), .scales (N,), .k
         self._lists = _lists           # cached host decode
         self._classes = tuple(classes) if classes is not None else None
+        # host copy of the four arrays (`fetch`), not a pytree leaf:
+        # the arrays themselves when none of them is on a device
+        arrays = (scores, index, keep, n_valid)
+        self._host = None if any(isinstance(a, jax.Array)
+                                 for a in arrays) else arrays
 
     # ------------------------------------------------------ constructors
     @classmethod
@@ -154,16 +161,19 @@ class Detections:
         return int(np.shape(self._scores)[0])
 
     def frame(self, i: int) -> "Detections":
-        """Slice one frame out of a batched result (no host sync; on the
-        device each slice is a small program of its own). Span:
-        `detect.slice`."""
+        """Slice one frame out of a batched result, keeping the class
+        axis. The slice is taken on the host from the batch's one host
+        copy (`fetch`, made on first use): the frame gets numpy views of
+        that copy and the decoded list, and no device program runs.
+        Span: `detect.slice`."""
         if not self.batched:
             raise ValueError("frame() on a single-frame Detections")
+        host = self._host_arrays()
         lists = None if self._lists is None else [self._lists[i]]
         with spans.span("detect.slice"):
-            return Detections(self._scores[i], self._index[i],
-                              self._keep[i], self._n_valid[i], self._tables,
-                              _lists=lists, classes=self._classes)
+            return Detections(*(np.asarray(a[i]) for a in host),
+                              self._tables, _lists=lists,
+                              classes=self._classes)
 
     def for_class(self, c) -> "Detections":
         """Slice one head (by name or index) out of a multi-class
@@ -183,15 +193,30 @@ class Detections:
         return self
 
     # ----------------------------------------------------------- decode
+    def fetch(self) -> bool:
+        """Copy the four result arrays to the host, once: one
+        `jax.device_get` starts every copy together (and waits for the
+        device). Returns True when this call made the copy, False when
+        the result already had one. Span: `detect.fetch`."""
+        if self._host is not None:
+            return False
+        with spans.span("detect.fetch"):
+            self._host = jax.device_get(
+                (self._scores, self._index, self._keep, self._n_valid))
+        return True
+
+    def _host_arrays(self) -> tuple:
+        self.fetch()
+        return self._host
+
     @property
     def saturated(self):
         """True when more candidates cleared the score threshold than
         the program's top-k (`max_detections`) could hold -- the tail
         was dropped BEFORE NMS. bool for a frame, (B,) array per batch;
         with a class axis the array keeps it ((K,) / (B, K)), one flag
-        per head."""
-        with spans.span("detect.fetch"):
-            n_valid = np.asarray(self._n_valid)
+        per head. Reads the host copy (`fetch`)."""
+        n_valid = np.asarray(self._host_arrays()[3])
         if self.batched or self._classes is not None:
             return n_valid > self._tables.k
         return bool(int(n_valid) > self._tables.k)
@@ -235,15 +260,10 @@ class Detections:
         return merged
 
     def _decoded(self) -> list:
-        """The host decode, cached. Spans: `detect.fetch`, the
-        device-to-host copies (which wait for the device), and
+        """The host decode of the host copy (`fetch`), cached. Span:
         `detect.decode`, the Python decode."""
         if self._lists is None:
-            with spans.span("detect.fetch"):
-                top = np.asarray(self._scores)
-                idx = np.asarray(self._index)
-                kp = np.asarray(self._keep)
-                nv = np.asarray(self._n_valid)
+            top, idx, kp, nv = (np.asarray(a) for a in self._host_arrays())
             with spans.span("detect.decode"):
                 if self.batched:
                     self._lists = [self._decode_frame(top[i], idx[i], kp[i],

@@ -229,6 +229,9 @@ class DetectionService:
                       "frames": 0, "frame_ms": 0.0, "frame_boxes": 0,
                       "frame_batches": 0, "frame_occupancy": 0.0,
                       "frame_rejects": 0, "frames_saturated": 0,
+                      # device-to-host result copies made to answer
+                      # frames: one a batch, one a lone frame
+                      "result_fetches": 0,
                       # kept-box counts per head label on multi-class
                       # sessions ({} until a labelled detection lands)
                       "class_boxes": {},
@@ -302,6 +305,7 @@ class DetectionService:
         self._emit.emit(
             "service_stop",
             frames=self.stats["frames"], batches=self.stats["frame_batches"],
+            result_fetches=self.stats["result_fetches"],
             answers=self.stats["frame_answers"],
             errors=self.stats["frame_errors"],
             deadline_shed=self.stats["deadline_shed"],
@@ -634,7 +638,12 @@ class DetectionService:
             return self._cascade.detect(frame), False
         if rung == "coarse":
             return self._cascade.detect_degraded(frame, "coarse"), False
-        res = self._reduced.detect_raw(frame)
+        return self._host_answer(self._reduced.detect_raw(frame))
+
+    def _host_answer(self, res) -> Tuple[List[dict], bool]:
+        """A result's (detections, saturated), read from its host copy;
+        counts the copy in `result_fetches` when this call made it."""
+        self.stats["result_fetches"] += res.fetch()
         return res.to_list(), bool(np.any(res.saturated))
 
     def _serve_frame_batch(self) -> bool:
@@ -654,8 +663,9 @@ class DetectionService:
         With spans on (obs/spans.py), `serve.batch` runs from the first
         request's pop to the last answer, with the children
         `serve.gather` (straggler wait and bucketing), `serve.run` (the
-        detector call), `serve.decode` (per-frame slice and decode) and
-        `serve.answer`.
+        detector call), `serve.decode` (one `detect.fetch` and one
+        `detect.decode` of the whole batch, then a `detect.slice` a
+        frame from that host copy) and `serve.answer`.
         """
         req = None
         while req is None:
@@ -736,9 +746,12 @@ class DetectionService:
                 # the legacy meaning (device step + host decode)
                 with spans.span("serve.decode"):
                     if len(group) > 1:
+                        # one host copy and decode of the batch; each
+                        # frame is then sliced from it on the host
+                        self.stats["result_fetches"] += batch.fetch()
+                        batch.to_list()
                         results = [batch.frame(i) for i in range(len(group))]
-                    dets_per = [(res.to_list(), bool(np.any(res.saturated)))
-                                for res in results]
+                    dets_per = [self._host_answer(res) for res in results]
             except Exception:
                 # batch failed as a whole: fall back to per-frame so one
                 # poisonous frame cannot fail its innocent batch-mates;
@@ -749,9 +762,8 @@ class DetectionService:
                 with spans.span("serve.run"):
                     for r in group:
                         try:
-                            res = self._detector.detect_raw(r.frame)
-                            dets_per.append((res.to_list(),
-                                             bool(np.any(res.saturated))))
+                            dets_per.append(self._host_answer(
+                                self._detector.detect_raw(r.frame)))
                         except Exception as e:
                             dets_per.append(e)
         else:
@@ -766,6 +778,10 @@ class DetectionService:
         ms = (time.perf_counter() - t0) * 1e3 / len(group)
         self.stats["frame_batches"] += 1
         self._account_device_frames(len(group))
+        # the batch ran: close the breaker before anyone is answered, so
+        # a client holding its answer never reads a stale `open`
+        self._breaker.record_success()
+        self.stats["breaker"] = self._breaker.snapshot()
         now = time.monotonic()
         with spans.span("serve.answer"):
             for r, dets in zip(group, dets_per):
@@ -806,8 +822,6 @@ class DetectionService:
         self.stats["latency_ms"] = self._latency.snapshot()
         self.stats["degraded_mode"] = self._ladder.rung
         self.stats["ladder"] = self._ladder.snapshot()
-        self._breaker.record_success()
-        self.stats["breaker"] = self._breaker.snapshot()
         # ------------------------------------------- metrics export (§15)
         if self._emit.active:
             devices_used = 1 if len(group) == 1 \

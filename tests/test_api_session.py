@@ -5,6 +5,7 @@ surfacing, warmup/cache stats, checkpoint round-trip, serve() wiring.
 import pathlib
 import warnings
 
+import jax
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -152,6 +153,103 @@ def test_saturated_flag_single_and_batch():
     with warnings.catch_warnings():
         warnings.simplefilter("ignore", RuntimeWarning)
         assert len(b.to_list()) == 2
+
+
+# ------------------------------------------- fetched batches: host slicing
+
+def _stacked_svm(n):
+    """n single-head SVMs stacked into one multi-class head set."""
+    rng = np.random.default_rng(9)
+    return {"w": jnp.asarray(rng.normal(size=(n, 3780)).astype(np.float32)
+                             * .01),
+            "b": jnp.zeros((n,), jnp.float32)}
+
+
+def _warned(fn):
+    """fn()'s result and the RuntimeWarning messages it raised."""
+    with warnings.catch_warnings(record=True) as got:
+        warnings.simplefilter("always", RuntimeWarning)
+        out = fn()
+    return out, [str(w.message) for w in got
+                 if w.category is RuntimeWarning]
+
+
+HOST_SLICE_CASES = {
+    "single": (SVM, CFG),
+    "single-saturated": (SVM, DetectorConfig(score_threshold=-1e9,
+                                             scales=(1.0,),
+                                             max_detections=4)),
+    "multiclass": (_stacked_svm(2), CFG),
+    "multiclass-saturated": (_stacked_svm(2), DetectorConfig(
+        score_threshold=-1e9, scales=(1.0,), max_detections=4)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(HOST_SLICE_CASES))
+def test_fetched_batch_frames_slice_on_the_host(case):
+    """A B=3 batch fetched once answers each frame from its host copy:
+    numpy arrays equal to the device slice with the class axis kept, the
+    answer and `saturated` of the per-frame `detect_raw`, the same
+    RuntimeWarnings, and no device program (the batch's device buffers
+    are deleted before any frame is sliced)."""
+    svm, cfg = HOST_SLICE_CASES[case]
+    fd = FrameDetector(svm, cfg)
+    frames = [_scene(i) for i in (1, 2, 3)]
+    lazy = fd.detect_batch_raw(frames)
+    batch = fd.detect_batch_raw(frames)
+    assert batch.fetch() is True and batch.fetch() is False
+    lists, warned = _warned(batch.to_list)
+    for leaf in jax.tree_util.tree_leaves(batch):
+        leaf.delete()
+    per_frame_warned = []
+    for i, f in enumerate(frames):
+        single = fd.detect_raw(f)
+        want, w = _warned(single.to_list)
+        per_frame_warned += w
+        got = batch.frame(i)
+        leaves = jax.tree_util.tree_leaves(got)
+        assert all(type(a) is np.ndarray for a in leaves)
+        for a, d in zip(leaves, jax.tree_util.tree_leaves(lazy.frame(i))):
+            assert a.shape == d.shape and a.dtype == d.dtype
+            np.testing.assert_array_equal(a, np.asarray(d))
+        assert got.fetch() is False
+        _assert_identical(want, got.to_list())
+        _assert_identical(want, lists[i])
+        np.testing.assert_array_equal(got.saturated, single.saturated)
+        assert type(got.saturated) is type(single.saturated)
+    assert warned == per_frame_warned
+    assert bool(warned) == case.endswith("saturated")
+
+
+@pytest.mark.parametrize("case", ["single", "multiclass"])
+def test_unfetched_batch_frame_fetches_the_batch_once(case):
+    """frame(i) of a batch never fetched makes the batch's one host copy
+    and slices it: every frame gets numpy arrays and the per-frame
+    `detect_raw` answer, and no frame copies anything again."""
+    svm, cfg = HOST_SLICE_CASES[case]
+    fd = FrameDetector(svm, cfg)
+    frames = [_scene(i) for i in (1, 2, 3)]
+    batch = fd.detect_batch_raw(frames)
+    for i, f in enumerate(frames):
+        got = batch.frame(i)
+        assert all(type(a) is np.ndarray
+                   for a in jax.tree_util.tree_leaves(got))
+        assert got.fetch() is False
+        _assert_identical(fd.detect_raw(f).to_list(), got.to_list())
+    assert batch.fetch() is False
+
+
+def test_host_built_results_need_no_fetch():
+    """Results made on the host (empty, from_list, stack) hold their
+    host copy already: fetch() copies nothing."""
+    ses = _session()
+    stacked = Detections.stack([ses.detect(_scene(i)) for i in (1, 2)])
+    empty = _session(DetectorConfig(scales=(1.0,))).detect(
+        np.zeros((64, 64, 3), np.uint8))
+    listed = Detections.from_list([{"box": (0.0, 0.0, 10.0, 5.0),
+                                    "score": 2.0, "scale": 1.0}])
+    for d in (stacked, stacked.frame(1), empty, listed):
+        assert d.fetch() is False
 
 
 def test_unsaturated_flag_false_no_warning():

@@ -21,6 +21,7 @@ import json
 import re
 import threading
 import time
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -489,8 +490,9 @@ def test_gc_and_compile_spans_nest_under_the_open_span():
 def test_served_batch_yields_nested_service_and_detector_spans(recorder):
     """A served batch on the CPU: `serve.batch` holds `serve.gather`,
     `serve.run` (holding `detect.stack`, `detect.upload`,
-    `detect.dispatch`), `serve.decode` (holding `detect.slice`,
-    `detect.fetch`, `detect.decode`) and `serve.answer`; each request's
+    `detect.dispatch`), `serve.decode` (holding one `detect.fetch` and
+    one `detect.decode` of the batch, and a `detect.slice` a frame) and
+    `serve.answer`; each request's
     `serve.request` names its own request and the batch that answered
     it."""
     svc = _service(frame_batch=3, max_wait_ms=2000.0,
@@ -522,12 +524,51 @@ def test_served_batch_yields_nested_service_and_detector_spans(recorder):
             assert by_id[s.parent].name == parent, name
             assert s.batch == batch.id
     assert len([s for s in got if s.name == "detect.slice"]) == 3
+    # the batch is fetched to the host once and each frame sliced there
+    assert len([s for s in got if s.name == "detect.fetch"]) == 1
+    assert len([s for s in got if s.name == "detect.decode"]) == 1
     for s in got:
         if s.batch == batch.id and s.name != "serve.request":
             assert batch.start <= s.start <= s.end <= batch.end, s.name
     reqs = [s for s in got if s.name == "serve.request"]
     assert len(reqs) == 3 and len({s.request for s in reqs}) == 3
     assert all(s.batch == batch.id and s.start < batch.start for s in reqs)
+
+
+@pytest.mark.parametrize("max_detections", [0, 4])
+def test_served_batches_fetch_their_results_once(tmp_path, max_detections):
+    """Over batched traffic the service copies each batch's results to
+    the host once (`result_fetches == frame_batches`, also in the
+    `service_stop` totals), and answers every frame as the per-frame
+    `detect_raw` does, `saturated` flags included (max_detections=4
+    saturates every frame)."""
+    cfg = dataclasses.replace(DET_CFG, max_detections=max_detections)
+    if max_detections:
+        cfg = dataclasses.replace(cfg, score_threshold=-1e9)
+    path = str(tmp_path / "fetch.jsonl")
+    svc = _service(detector=cfg, frame_batch=3, max_wait_ms=2000.0,
+                   metrics=MetricsConfig(jsonl_path=path))
+    frames = _frames(6)
+    svc.start()
+    try:
+        futs = [svc.submit_frame(f) for f in frames]
+        answers = [f.get(timeout=120) for f in futs]
+    finally:
+        svc.stop()
+    st = svc.stats
+    assert st["frames"] == 6 and st["batch_fallbacks"] == 0
+    assert st["frame_batches"] < 6                 # batches did form
+    assert st["result_fetches"] == st["frame_batches"]
+    (stop,) = [e for e in JsonlSink.read(path)
+               if e["kind"] == "service_stop"]
+    assert stop["result_fetches"] == st["result_fetches"]
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)
+        for f, a in zip(frames, answers):
+            res = svc._detector.detect_raw(f)
+            assert a["detections"] == res.to_list()
+            assert a["saturated"] == bool(np.any(res.saturated))
+            assert a["saturated"] == bool(max_detections)
 
 
 # ====================================== frame program scopes and loads
