@@ -30,8 +30,11 @@ Presets fold in the paper-workload variants from configs/hog_svm.py:
                           frames >= 1280x720 split their pyramid over
                           every visible device (detector.frame_parallel=0,
                           row-slab tiles, banded resize) with an exact
-                          top-k merge -- single-frame UHD latency path,
-                          box-identical to untiled (DESIGN.md §11)
+                          top-k merge -- single-frame UHD latency path;
+                          byte-identical to untiled on CPU host devices
+                          (tests/test_tiled.py); on TPU chips the same
+                          boxes, with float scores that may differ in
+                          their low bits (DESIGN.md §11)
     presets("quant")      the paper's fixed-point datapath end to end:
                           integer CORDIC gradient/bin unit, int16 cell
                           histograms, int8 block descriptors with

@@ -58,23 +58,28 @@ The TILED path adds intra-frame parallelism on top of both: with
 score grid (exact descriptor halo) or by whole scale-groups
 (cfg.tile_mode). Each tile emits a local top-k; an exact union re-rank
 (core/tiling.py:merge_topk) plus one nms_keep pass reproduce the
-untiled result box-identically (tests/test_tiled.py), taking worst-case
-single-frame latency from one chip to all of them (DESIGN.md §11).
+untiled result (byte for byte on CPU host devices, tests/test_tiled.py;
+the same boxes on TPU chips, DESIGN.md §11), taking worst-case
+single-frame latency from one chip to all of them. A single tiled frame
+reaches the chips as row bands, one a chip (`_banded_put`).
 
 STAGES. Each stage of the frame program is wrapped in `jax.named_scope`
 where it is defined, so every variant (single, batched, sharded, tiled)
 carries the names in its HLO metadata (`op_name`) and in the device
 trace: `gray` (_prep_frame), `resize` (each pyramid level), `hog`
 (scene_blocks), `score/matmul` and `score/collate` (score_blocks),
-`select` (threshold and top-k) and `nms` (nms_keep). The scopes change
-metadata only, not the operations or their names. `op_scopes(batch)`
-reads them back from the compiled text of the programs dispatched.
+`select` (threshold and top-k) and `nms` (nms_keep); the tiled programs
+add `merge` (the gather and re-rank of the tiles' top-k lists). The
+scopes change metadata only, not the operations or their names.
+`op_scopes(batch)` reads them back from the compiled text of the
+programs dispatched.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
 import re
+import threading
 import time
 from functools import lru_cache, partial
 from typing import Callable, List, Optional, Sequence, Tuple
@@ -82,7 +87,7 @@ from typing import Callable, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from jax import shard_map
 from repro.core import numerics as N, quant
@@ -144,8 +149,8 @@ class DetectorConfig:
     #   (ph * pw) >= frame_parallel_min_area route to the tiled path:
     #   per-tile local top-k under shard_map over the 'tile' mesh axis
     #   (launch/mesh.py:make_tiled_mesh), then an exact union re-rank +
-    #   one NMS pass -- box-identical to the untiled program
-    #   (core/tiling.py, DESIGN.md §11). Composes with data_parallel as
+    #   one NMS pass -- the untiled program's boxes (core/tiling.py,
+    #   DESIGN.md §11). Composes with data_parallel as
     #   a 2-D (data, tile) schedule for batches.
     tile_mode: str = "slab"               # intra-frame decomposition:
     #   "slab" = row-slabs of each scale's score grid (halo recompute,
@@ -711,8 +716,10 @@ def _sharded_batch_fn(h: int, w: int, ph: int, pw: int, batch: int,
 # scale's score grid (with an exact descriptor halo) or a whole
 # scale-group -- and produces its local top-k; tiling.merge_topk then
 # re-ranks the union exactly and ONE nms_keep pass over the merged list
-# reproduces the untiled keep set, so results are box-identical to the
-# untiled program per backend/numerics mode (tests/test_tiled.py).
+# reproduces the untiled keep set, so results are byte-identical to the
+# untiled program per backend/numerics mode on CPU host devices
+# (tests/test_tiled.py); on TPU chips the boxes agree and the float
+# scores may not (DESIGN.md §11).
 
 
 def _resolve_fp(cfg: DetectorConfig, dp: Optional[int] = None) -> int:
@@ -964,15 +971,57 @@ def _tile_local_fn(ph: int, pw: int, fp: int,
     return local
 
 
+_TILED_UPLOADS = {"frames": 0, "bytes": {}}
+_TILED_UPLOADS_LOCK = threading.Lock()
+
+
+def _banded_put(image, fp: int) -> Array:
+    """A frame to the `fp` chips of the 'tile' axis as row bands, each
+    chip receiving only its own rows. An RGB frame travels as (rows,
+    w*3): a minor dimension of 3 made a v5e's host-to-chip copy four
+    times slower (PERF.md). A frame whose rows do not split evenly gets
+    zero rows at the bottom up to a multiple of `fp`, which the program
+    drops (`_tiled_single_fn`). The bytes placed on each device and the
+    frames are counted (`tiled_uploads`). A fresh buffer every call, so
+    the program may donate it."""
+    host = np.asarray(image)
+    extra = -host.shape[0] % fp
+    if extra:
+        host = np.pad(host, ((0, extra),) + ((0, 0),) * (host.ndim - 1))
+    host = host.reshape(host.shape[0], -1)
+    sharding = NamedSharding(_tile_mesh(1, fp), P("tile"))
+    frame = jax.device_put(host, sharding)
+    band = int(np.prod(sharding.shard_shape(host.shape))) * host.itemsize
+    with _TILED_UPLOADS_LOCK:
+        counts = _TILED_UPLOADS["bytes"]
+        for d in sharding.addressable_devices:
+            counts[d.id] = counts.get(d.id, 0) + band
+        _TILED_UPLOADS["frames"] += 1
+    return frame
+
+
+def tiled_uploads() -> dict:
+    """What the tiled path's uploads placed, process-wide: {"frames": n,
+    "bytes": {device id: bytes}}."""
+    with _TILED_UPLOADS_LOCK:
+        return {"frames": _TILED_UPLOADS["frames"],
+                "bytes": dict(_TILED_UPLOADS["bytes"])}
+
+
 @lru_cache(maxsize=64)
 def _tiled_single_fn(h: int, w: int, ph: int, pw: int, fp: int,
                      cfg: DetectorConfig) -> "jax.stages.Wrapped":
-    """Single-frame tiled program: the per-tile local program under
-    shard_map over the 'tile' axis (frame + SVM params replicated),
-    stacked local top-k lists out, then ONE exact merge + NMS in the
-    enclosing jit -- the merge runs once, not replicated per tile, which
-    matters on hosts where forced devices share cores. Same signature
-    and donation contract as _single_fn."""
+    """Single-frame tiled program: takes the frame as `fp` row bands
+    (`_banded_put`: the true h rows padded to a multiple of fp, an RGB
+    frame as (rows, w*3)). Under shard_map over the 'tile' axis each
+    tile converts its own band to gray, and the bands are gathered into
+    the whole gray plane that every tile's local program reads (the
+    `gray` scope), padded to the bucket as the untiled program pads it;
+    the local top-k lists come out stacked, then ONE exact merge (the
+    `merge` scope) + NMS in the enclosing jit -- the merge runs once,
+    not replicated per tile, which matters on hosts where forced
+    devices share cores. The frame buffer is donated on accelerators,
+    as in _single_fn."""
     from repro.core.tiling import merge_topk
     base = _frame_program(ph, pw, cfg)
     if base.raw is None:
@@ -981,19 +1030,23 @@ def _tiled_single_fn(h: int, w: int, ph: int, pw: int, fp: int,
     boxes_dev = jnp.asarray(base.boxes)
     mesh = _tile_mesh(1, fp)
 
-    def tile_fn(gray: Array, wv: Array, bv: Array, hw: Array):
-        t, i, v = local(gray, wv, bv, hw)
+    def tile_fn(band: Array, wv: Array, bv: Array, hw: Array):
+        with jax.named_scope("gray"):
+            g = grayscale(band.reshape(band.shape[0], w, 3)) \
+                if band.shape[1] == 3 * w else band.astype(jnp.float32)
+            gray = jax.lax.all_gather(g, "tile", tiled=True)[:h]
+        t, i, v = local(_prep_frame(gray, h, w, ph, pw), wv, bv, hw)
         return t[None], i[None], v[None]
 
     sm = shard_map(tile_fn, mesh=mesh,
-                   in_specs=(P(), P(), P(), P()),
+                   in_specs=(P("tile"), P(), P(), P()),
                    out_specs=(P("tile"), P("tile"), P("tile")),
                    check_vma=False)
 
     def fn(frame: Array, wv: Array, bv: Array, hw: Array):
-        gray = _prep_frame(frame, h, w, ph, pw)
-        tl, il, nl = sm(gray, wv, bv, hw)
-        top, idx = merge_topk(tl, il, base.k)
+        tl, il, nl = sm(frame, wv, bv, hw)
+        with jax.named_scope("merge"):
+            top, idx = merge_topk(tl, il, base.k)
         keep = nms_keep(boxes_dev[idx], top, cfg.nms_iou)
         return top, idx, keep, jnp.sum(nl)
 
@@ -1027,10 +1080,11 @@ def _tiled_batch_fn(h: int, w: int, ph: int, pw: int, batch: int,
     def one(frame: Array, wv: Array, bv: Array, hw: Array):
         gray = _prep_frame(frame, h, w, ph, pw)
         t, i, v = local(gray, wv, bv, hw)
-        tl = jax.lax.all_gather(t, "tile")              # (fp, k)
-        il = jax.lax.all_gather(i, "tile")
-        nv = jax.lax.psum(v, "tile")
-        top, idx = merge_topk(tl, il, base.k)
+        with jax.named_scope("merge"):
+            tl = jax.lax.all_gather(t, "tile")          # (fp, k)
+            il = jax.lax.all_gather(i, "tile")
+            nv = jax.lax.psum(v, "tile")
+            top, idx = merge_topk(tl, il, base.k)
         keep = nms_keep(boxes_dev[idx], top, cfg.nms_iou)
         return top, idx, keep, nv
 
@@ -1165,9 +1219,13 @@ def _dispatch(fn, args: tuple, bucket: Tuple[int, int], batch: int):
     its first call."""
     new = fn not in _PROGRAMS
     if new:
+        # an uncommitted argument goes wherever the program runs, so it
+        # is noted without a placement (with one, a program over several
+        # devices would refuse it)
         avals = tuple(jax.ShapeDtypeStruct(
             np.shape(a), jnp.result_type(a),
-            sharding=getattr(a, "sharding", None)) for a in args)
+            sharding=a.sharding if getattr(a, "committed", False)
+            else None) for a in args)
     with spans.span("detect.dispatch"):
         out = fn(*args)
     if new:
@@ -1366,8 +1424,10 @@ class FrameDetector:
         decodes lazily on first host access (`.to_list()` et al.).
         Grayscale + pad run inside the program (one dispatch per frame,
         keyed on the true shape like the batch path), and the frame
-        buffer is donated on accelerators. Spans: `detect.upload`, the
-        frame to the device; `detect.dispatch`, enqueueing the program.
+        buffer is donated on accelerators. A tiled frame goes to its
+        chips as row bands, one a chip (`_banded_put`); an untiled one
+        whole to the default device. Spans: `detect.upload`, the frame
+        to the device(s); `detect.dispatch`, enqueueing the program.
         """
         from repro.api.results import Detections
         h, w = _frame_hw(np.shape(image))
@@ -1378,11 +1438,15 @@ class FrameDetector:
         fn = (_tiled_single_fn(h, w, ph, pw, fp, self.cfg) if fp > 1
               else _single_fn(h, w, ph, pw, self.cfg))
         with spans.span("detect.upload"):
-            frame = jnp.asarray(image)
-            if _donate() and isinstance(image, jax.Array):
-                # the program donates its frame argument; a caller-owned
-                # device buffer must not be invalidated under them
-                frame = jnp.array(frame, copy=True)
+            if fp > 1:
+                frame = _banded_put(image, fp)
+            else:
+                frame = jnp.asarray(image)
+                if _donate() and isinstance(image, jax.Array):
+                    # the program donates its frame argument; a
+                    # caller-owned device buffer must not be invalidated
+                    # under them
+                    frame = jnp.array(frame, copy=True)
             hw = jnp.asarray([h, w], jnp.float32)
         top, idx, keep, n_valid = _dispatch(
             fn, (frame, self.svm["w"], self.svm["b"], hw), (ph, pw), 1)

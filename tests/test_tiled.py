@@ -21,8 +21,10 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core.detector import (DetectorConfig, FrameDetector,
-                                 _autotune_key_str, _frame_program,
-                                 _resolve_fp, _resolve_k, _tiled_single_fn)
+                                 _autotune_key_str, _banded_put,
+                                 _frame_program, _resolve_fp, _resolve_k,
+                                 _tiled_single_fn, hlo_op_names, op_scopes,
+                                 tiled_uploads)
 from repro.core.hog import PAPER_HOG
 from repro.core.tiling import (band_rows, band_weights, extend_band,
                                merge_topk, resize_banded, scale_groups,
@@ -291,3 +293,117 @@ def test_area_threshold_routes_small_frames_untiled():
     assert routed.frame_devices == jax.device_count()
     assert routed.detect_raw(frame).to_list() == want
     assert _tiled_single_fn.cache_info().misses == misses
+
+
+# ------------------------------------- the banded upload of the tiled path
+
+def _perf_tiled(fp, **kw):
+    """The perf preset as the four-chip 4K cell runs it
+    (chipbench/configs/uhd_tiled4.json), at a small frame size: fused
+    Pallas HOG in bf16, MXU scoring, banded resize, 3 levels."""
+    from repro.api import presets
+    return dataclasses.replace(
+        presets("perf").detector, scales=(1.0, 0.8, 0.64),
+        max_detections=0, pyramid_resize="banded", frame_parallel=fp,
+        tile_mode="slab", **kw)
+
+
+@multi
+@pytest.mark.parametrize("h,w,rows", [
+    (2160, 3840, 540),            # the 4K frame: a quarter on each chip
+    (322, 256, 81),               # rows that do not split: zero rows added
+])
+def test_banded_upload_puts_a_row_band_on_each_tile(h, w, rows):
+    if jax.device_count() < 4:
+        pytest.skip("needs 4 devices")
+    frame = np.zeros((h, w, 3), np.uint8)
+    frame[h - 1] = 7
+    before = tiled_uploads()
+    put = _banded_put(frame, 4)
+    shards = sorted(put.addressable_shards, key=lambda s: s.index[0].start)
+    assert [s.device.id for s in shards] == [0, 1, 2, 3]
+    assert all(s.data.shape[0] == rows for s in shards)
+    assert put.shape == (4 * rows, w * 3)
+    flat = np.asarray(put).reshape(4 * rows, w, 3)
+    assert np.array_equal(flat[:h], frame) and not flat[h:].any()
+    after = tiled_uploads()
+    assert after["frames"] == before["frames"] + 1
+    for d in range(4):
+        assert after["bytes"][d] - before["bytes"].get(d, 0) == \
+            rows * w * 3
+
+
+@multi
+@pytest.mark.parametrize("h,w", [(320, 256), (322, 160)])
+def test_banded_upload_answers_as_the_whole_frame_and_untiled(h, w):
+    """The perf preset tiled over 4 with the frame uploaded in row bands
+    answers byte for byte as the same program given the whole frame on
+    one device, and as the untiled program; 322 rows do not split over
+    4 tiles."""
+    if jax.device_count() < 4:
+        pytest.skip("needs 4 devices")
+    frame = _frame(h, w)
+    svm = {"w": SVM["w"] * 100.0, "b": SVM["b"]}
+    cfg = _perf_tiled(4, score_threshold=-5.0)
+    tiled = FrameDetector(svm, cfg)
+    banded = tiled.detect_raw(frame)
+    _, ph, pw = tiled.program_for(h, w)
+    whole = _tiled_single_fn(h, w, ph, pw, 4, cfg)(
+        jnp.asarray(np.pad(frame, ((0, -h % 4), (0, 0), (0, 0)))
+                    .reshape(-1, w * 3)),
+        svm["w"], svm["b"], jnp.asarray([h, w], jnp.float32))
+    leaves = jax.tree_util.tree_leaves(banded)
+    assert len(leaves) == len(whole) == 4
+    for a, b in zip(leaves, whole):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    want = FrameDetector(svm, dataclasses.replace(
+        cfg, frame_parallel=1)).detect_raw(frame).to_list()
+    assert want, "threshold must admit boxes or the test is vacuous"
+    assert banded.to_list() == want
+
+
+@multi
+def test_banded_tiled_answers_agree_with_the_plain_reference():
+    """People in a seeded clip, the benchmark's trained SVM: the tiled
+    perf path with the banded upload keeps to the benchmark's own
+    comparison (chipbench/compare.py) against the float64 reference."""
+    if jax.device_count() < 4:
+        pytest.skip("needs 4 devices")
+    import json
+    import pathlib
+    import sys
+    root = pathlib.Path(__file__).resolve().parents[1]
+    sys.path.insert(0, str(root))
+    from chipbench import compare
+    from chipbench.clips import make_clip
+    svm = json.loads((root / "chipbench/svm/svm.json").read_text())
+    frame = make_clip(np.random.default_rng(0), 320, 256, 1, 3)[0]
+    cfg = _perf_tiled(4)
+    dets = FrameDetector({"w": jnp.asarray(svm["w"], jnp.float32),
+                          "b": jnp.float32(svm["b"])},
+                         cfg).detect_raw(frame).to_list()
+    got = compare.check_frame(
+        dets, frame, np.asarray(svm["w"], np.float64), float(svm["b"]),
+        {"detector": {"scales": list(cfg.scales), "shape_bucket": 32,
+                      "score_threshold": cfg.score_threshold,
+                      "nms_iou": cfg.nms_iou, "max_detections": 0}})
+    assert got["boxes"] and got["ref_boxes"], got
+    assert got["score_gap"] <= compare.LIMITS["score_gap"], got
+    assert got["unexplained"] == 0 and got["missed"] == 0, got
+
+
+@multi
+def test_the_tiled_program_scopes_its_merge():
+    """`op_scopes(1)` of a dispatched tiled program holds operations of
+    the `merge` scope, the one the benchmark's merge reader matches."""
+    if jax.device_count() < 4:
+        pytest.skip("needs 4 devices")
+    cfg = _perf_tiled(4)
+    FrameDetector(SVM, cfg).detect_raw(_frame(320, 256))
+    fn = _tiled_single_fn(320, 256, 320, 256, 4, cfg)
+    from repro.core import detector
+    names = hlo_op_names(
+        fn.lower(*detector._PROGRAMS[fn]["args"]).compile().as_text())
+    merged = {op for op, path in names.items()
+              if "merge" in path.split("/")}
+    assert merged and merged <= set(op_scopes(1))
