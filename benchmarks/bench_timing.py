@@ -440,8 +440,8 @@ def run_stage_breakdown(fast: bool = False) -> dict:
     fn = _single_fn(h, w, ph, pw, cfg)
     mk = (lambda f: jnp.array(f, copy=True)) if _donate() \
         else (lambda f: f)
-    t_prog = _time(lambda f: fn(mk(f), svm["w"], svm["b"], hw_v), frame,
-                   iters=iters)
+    t_prog = _time(lambda f: fn(mk(f), svm["w"], svm["b"], hw_v),
+                   frame.reshape(h, w * 3), iters=iters)
 
     dense_ms, calib_ms = _measure_dense_and_calib(
         det, np.asarray(frame), rounds=3 if fast else 5)

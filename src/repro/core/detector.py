@@ -63,6 +63,11 @@ the same boxes on TPU chips, DESIGN.md §11), taking worst-case
 single-frame latency from one chip to all of them. A single tiled frame
 reaches the chips as row bands, one a chip (`_banded_put`).
 
+UPLOADS. Every RGB frame crosses to the chip as interleaved rows,
+(..., h, w*3), and every program turns it into gray by its static shape
+(`_luma`): a minor dimension of 3 made a v5e's host-to-chip copy four
+times slower. `frame_uploads()` counts the frames handed over by layout.
+
 STAGES. Each stage of the frame program is wrapped in `jax.named_scope`
 where it is defined, so every variant (single, batched, sharded, tiled)
 carries the names in its HLO metadata (`op_name`) and in the device
@@ -550,15 +555,28 @@ def _frame_program(ph: int, pw: int, cfg: DetectorConfig) -> FrameProgram:
                         tables=DecodeTables(boxes_tab, scale_tab, k))
 
 
+def _luma(frame: Array, w: int) -> Array:
+    """The float32 gray plane of a frame of rows `w` pixels wide, read
+    by its static shape: a last dim of 3*w is interleaved RGB rows (the
+    layout every RGB frame travels in, `_upload`), one of w is gray."""
+    if frame.shape[-1] == 3 * w:
+        return grayscale(frame.reshape(frame.shape[:-1] + (w, 3)))
+    if frame.shape[-1] == w:
+        return frame.astype(jnp.float32)
+    raise ValueError(
+        f"expected rows of {w} gray or {3 * w} interleaved RGB values, "
+        f"got shape {tuple(frame.shape)}")
+
+
 def _prep_frame(frame: Array, h: int, w: int, ph: int, pw: int) -> Array:
-    """In-program frame prep shared by the single and batched programs:
-    grayscale (RGB input only) + edge-pad to the bucket. Runs INSIDE
-    the jit so uint8 stays on the wire, XLA fuses the luma into the
-    gradient stage, and the conversion happens once per frame -- every
-    pyramid scale then resizes the one gray buffer."""
+    """In-program frame prep shared by every frame program: grayscale
+    of an (h, w*3) interleaved RGB frame, or an (h, w) gray one as it
+    is (`_luma`), then edge-pad to the bucket. Runs INSIDE the jit so
+    uint8 stays on the wire, XLA fuses the luma into the gradient
+    stage, and the conversion happens once per frame -- every pyramid
+    scale then resizes the one gray buffer."""
     with jax.named_scope("gray"):
-        g = grayscale(frame) if frame.ndim == 3 \
-            else frame.astype(jnp.float32)
+        g = _luma(frame, w)
         if (ph, pw) != (h, w):
             g = jnp.pad(g, ((0, ph - h), (0, pw - w)), mode="edge")
         return g
@@ -568,9 +586,10 @@ def _prep_frame(frame: Array, h: int, w: int, ph: int, pw: int) -> Array:
 def _single_fn(h: int, w: int, ph: int, pw: int,
                cfg: DetectorConfig) -> "jax.stages.Wrapped":
     """The per-frame program with grayscale + pad fused in: raw frame
-    (h, w[, 3]) -> (top, idx, keep, n_valid). One jit per (true-shape,
-    bucket) pair; the frame buffer is donated on accelerators (the
-    program owns it -- detect_raw hands over a fresh buffer)."""
+    (h, w*3) interleaved RGB or (h, w) gray -> (top, idx, keep,
+    n_valid). One jit per (true-shape, bucket) pair; the frame buffer is
+    donated on accelerators (the program owns it -- detect_raw hands
+    over a fresh buffer)."""
     base = _frame_program(ph, pw, cfg)
     if base.raw is None:
         return None
@@ -588,18 +607,19 @@ def _batch_fn(h: int, w: int, ph: int, pw: int, batch: int,
     """The per-bucket program vmapped over a stacked frame batch.
 
     One jit per (true-shape, shape-bucket, B) tuple: raw frames
-    (B, h, w[, 3]) and the true (h, w) mask are batched, SVM params
-    broadcast. Grayscale conversion and edge-pad to the bucket run
-    INSIDE the program (uint8 stays on the wire; XLA fuses the luma
-    into the gradient stage), so the host does zero per-frame prep
-    dispatches. Keying on the true shape is the price of the fused
-    prep: uniform batches of DIFFERENT true shapes in one bucket
-    compile separate programs (bounded by the lru cache and, in
-    practice, by the handful of camera geometries a deployment sees);
-    mixed-shape batches take the pre-padded host path, which reuses
-    the single (bucket, B) program. The batch axis is mapped in `cfg.batch_chunk`-wide
-    vmapped chunks (lax.map): chunk 1 scans frame-by-frame (keeps each
-    frame's pyramid cache-resident on CPU hosts), chunk >= B is one
+    (B, h, w*3) interleaved RGB or (B, h, w) gray and the true (h, w)
+    mask are batched, SVM params broadcast. Grayscale conversion and
+    edge-pad to the bucket run INSIDE the program (uint8 stays on the
+    wire; XLA fuses the luma into the gradient stage), so the host does
+    zero per-frame prep dispatches. Keying on the true shape is the
+    price of the fused prep: uniform batches of DIFFERENT true shapes
+    in one bucket compile separate programs (bounded by the lru cache
+    and, in practice, by the handful of camera geometries a deployment
+    sees); mixed-shape batches take the pre-padded host path, which
+    reuses the single (bucket, B) program. The batch axis is mapped in
+    `cfg.batch_chunk`-wide vmapped chunks (lax.map): chunk 1 scans
+    frame-by-frame (keeps each frame's pyramid cache-resident on CPU
+    hosts), chunk >= B is one
     fully vectorized vmap step (wide accelerators); cfg.batch_chunk==0
     resolves the choice by measurement BEFORE this cache is consulted
     (_autotune_chunk). `donate` hands the frame-stack buffer to the
@@ -1008,6 +1028,47 @@ def tiled_uploads() -> dict:
                 "bytes": dict(_TILED_UPLOADS["bytes"])}
 
 
+_FRAME_UPLOADS = {layout: {"frames": 0, "bytes": 0}
+                  for layout in ("interleaved", "gray", "minor3")}
+_FRAME_UPLOADS_LOCK = threading.Lock()
+
+
+def _note_upload(frame: Array, w: int, frames: int) -> None:
+    """Count `frames` frames of rows `w` pixels wide handed to a frame
+    program as `frame`, under its layout (`frame_uploads`)."""
+    layout = "interleaved" if frame.shape[-1] == 3 * w else \
+        "gray" if frame.shape[-1] == w else "minor3"
+    with _FRAME_UPLOADS_LOCK:
+        counts = _FRAME_UPLOADS[layout]
+        counts["frames"] += frames
+        counts["bytes"] += frame.nbytes
+
+
+def frame_uploads() -> dict:
+    """Frames and bytes `detect_raw`, `detect_batch_raw` and the
+    mixed-size path handed to the frame programs, process-wide, by
+    layout: {"interleaved" | "gray" | "minor3": {"frames": n, "bytes":
+    b}}. `interleaved` is RGB as (..., h, w*3) rows, `minor3` RGB as
+    (..., h, w, 3), which a v5e copies four times slower (PERF.md) and
+    no path uploads."""
+    with _FRAME_UPLOADS_LOCK:
+        return {k: dict(v) for k, v in _FRAME_UPLOADS.items()}
+
+
+def _upload(image, rgb: bool, w: int, frames: int) -> Array:
+    """A frame, or a stack of `frames`, for an untiled program: RGB
+    (..., h, w, 3) as interleaved rows (..., h, w*3), gray as it is. A
+    host array is reshaped as a view before its copy to the device; a
+    caller's jax.Array is reshaped on the device, into a new buffer (a
+    gray one is returned as it is, still the caller's)."""
+    if not isinstance(image, jax.Array):
+        image = np.asarray(image)
+    frame = image.reshape(image.shape[:-2] + (w * 3,)) if rgb else image
+    frame = jnp.asarray(frame)
+    _note_upload(frame, w, frames)
+    return frame
+
+
 @lru_cache(maxsize=64)
 def _tiled_single_fn(h: int, w: int, ph: int, pw: int, fp: int,
                      cfg: DetectorConfig) -> "jax.stages.Wrapped":
@@ -1032,9 +1093,8 @@ def _tiled_single_fn(h: int, w: int, ph: int, pw: int, fp: int,
 
     def tile_fn(band: Array, wv: Array, bv: Array, hw: Array):
         with jax.named_scope("gray"):
-            g = grayscale(band.reshape(band.shape[0], w, 3)) \
-                if band.shape[1] == 3 * w else band.astype(jnp.float32)
-            gray = jax.lax.all_gather(g, "tile", tiled=True)[:h]
+            gray = jax.lax.all_gather(_luma(band, w), "tile",
+                                      tiled=True)[:h]
         t, i, v = local(_prep_frame(gray, h, w, ph, pw), wv, bv, hw)
         return t[None], i[None], v[None]
 
@@ -1116,7 +1176,7 @@ def _autotune_chunk(h: int, w: int, ph: int, pw: int, batch: int,
                     frame_dtype, dp: int = 1, fp: int = 1,
                     heads: int = 0) -> int:
     from repro.core import autotune_cache
-    layout = f"{'rgb' if len(frame_shape) == 4 else 'gray'}-{frame_dtype}"
+    layout = f"{'rgb' if frame_shape[-1] == 3 * w else 'gray'}-{frame_dtype}"
     # `heads` rides at the END of the key so the k[7]/k[8] mesh indices
     # in _autotune_key_str stay valid for pre-existing entries; 0 = the
     # single-head (F,) parameter layout, K>0 = stacked (K, F) heads
@@ -1393,11 +1453,8 @@ class FrameDetector:
 
     @staticmethod
     def _to_gray(image: Array) -> Array:
-        _frame_hw(np.shape(image))
-        gray = jnp.asarray(image)
-        if gray.ndim == 3:
-            gray = grayscale(gray)
-        return gray.astype(jnp.float32)
+        _, w = _frame_hw(np.shape(image))
+        return _luma(_upload(image, np.ndim(image) == 3, w, 1), w)
 
     def bucket_for(self, frame) -> Tuple[int, int]:
         """Padded-bucket shape a frame would be served under; raises
@@ -1426,8 +1483,10 @@ class FrameDetector:
         keyed on the true shape like the batch path), and the frame
         buffer is donated on accelerators. A tiled frame goes to its
         chips as row bands, one a chip (`_banded_put`); an untiled one
-        whole to the default device. Spans: `detect.upload`, the frame
-        to the device(s); `detect.dispatch`, enqueueing the program.
+        whole to the default device. Either way an (h, w, 3) RGB frame
+        travels as (h, w*3) interleaved rows (`_upload`) and a gray one
+        as it is. Spans: `detect.upload`, the frame to the device(s);
+        `detect.dispatch`, enqueueing the program.
         """
         from repro.api.results import Detections
         h, w = _frame_hw(np.shape(image))
@@ -1440,9 +1499,10 @@ class FrameDetector:
         with spans.span("detect.upload"):
             if fp > 1:
                 frame = _banded_put(image, fp)
+                _note_upload(frame, w, 1)
             else:
-                frame = jnp.asarray(image)
-                if _donate() and isinstance(image, jax.Array):
+                frame = _upload(image, np.ndim(image) == 3, w, 1)
+                if _donate() and frame is image:
                     # the program donates its frame argument; a
                     # caller-owned device buffer must not be invalidated
                     # under them
@@ -1463,9 +1523,10 @@ class FrameDetector:
         """Batched frame path: B frames -> one batched Detections.
 
         `frames` is a stacked (B, H, W[, 3]) array or a sequence of
-        frames. All frames must land in the SAME padded shape bucket
-        (equal shapes always do; the serving microbatcher groups by
-        bucket before calling) -- mixed buckets raise ValueError. The
+        frames; an RGB stack travels as (B, H, W*3) interleaved rows
+        (`_upload`). All frames must land in the SAME padded shape
+        bucket (equal shapes always do; the serving microbatcher groups
+        by bucket before calling) -- mixed buckets raise ValueError. The
         compiled program is the single-frame pyramid program vmapped
         over the batch, jitted once per (bucket, B) pair; per-frame
         top-k + NMS run device-side and the host never syncs until the
@@ -1529,7 +1590,7 @@ class FrameDetector:
         n_pad = _round_up(n, dp) if dp > 1 else n
         with spans.span("detect.upload"):
             if uniform:
-                frames_b = jnp.asarray(batch)
+                frames_b = _upload(batch, len(shape) == 4, w, n)
             else:
                 frames_b = jnp.stack([self._pad_to(g, ph, pw)
                                       for g in grays])
@@ -1543,11 +1604,11 @@ class FrameDetector:
                                 frames_b.dtype)
                 frames_b = jnp.concatenate([frames_b, pad])
                 hws = list(hws) + [(0, 0)] * (n_pad - n)
-            elif _donate() and isinstance(frames, jax.Array):
+            elif _donate() and frames_b is frames:
                 # the batched program donates its frame stack; only
-                # copy when the caller handed us their own device buffer
-                # (lists, numpy stacks and the pad concatenate above all
-                # produced a fresh one already)
+                # copy when it is still the caller's own device buffer
+                # (lists, numpy stacks, an RGB reshape and the pad
+                # concatenate above all produced a fresh one already)
                 frames_b = jnp.array(frames_b, copy=True)
             hw_b = jnp.asarray(hws, jnp.float32)
         fp = self._tiled_for(ph, pw, dp)

@@ -128,3 +128,26 @@ def test_probe_persists_and_warm_start_restores(monkeypatch, tmp_path):
     det.detect_batch_raw(frames)
     assert autotune_cache.stats()["memory_hits"] == 1
     detector._AUTOTUNE.update(saved)
+
+
+@pytest.mark.parametrize("rgb", [True, False], ids=["rgb", "gray"])
+def test_interleaved_rgb_probe_keeps_the_rgb_key(rgb):
+    """An RGB batch reaches the probe as (B, h, w*3) interleaved rows
+    and still keys as `rgb-uint8`, as the on-disk entries do; a gray
+    batch keys as `gray-uint8`. The probe compiles the program the call
+    then runs, noted with the same frame argument."""
+    cfg = DetectorConfig(score_threshold=-9.5 - 0.0625 * rgb, scales=(1.0,),
+                         batch_chunk=0)
+    shape = (2, 160, 128, 3) if rgb else (2, 160, 128)
+    frames = RNG.integers(0, 256, shape).astype(np.uint8)
+    FrameDetector(SVM, cfg).detect_batch_raw(frames)
+    layout = "rgb-uint8" if rgb else "gray-uint8"
+    key = detector._autotune_key_str(
+        (160, 128, 160, 128, 2, cfg, layout, 1, 1))
+    entry = detector.autotune_report()[key]
+    assert entry["source"] == "probe"
+    chosen = dataclasses.replace(cfg, batch_chunk=entry["chunk"])
+    fn = detector._batch_fn(160, 128, 160, 128, 2, chosen,
+                            detector._donate())
+    assert detector._PROGRAMS[fn]["args"][0].shape == \
+        (2, 160, 128 * 3 if rgb else 128)

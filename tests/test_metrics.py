@@ -583,13 +583,13 @@ def lowered_programs():
     frame size where both pyramid levels hold a window."""
     from repro.core.detector import _batch_fn, _single_fn
     h, w = 200, 100
-    fr = jax.ShapeDtypeStruct((h, w, 3), jnp.uint8)
+    fr = jax.ShapeDtypeStruct((h, w * 3), jnp.uint8)
     wv = jax.ShapeDtypeStruct((3780,), jnp.float32)
     bv = jax.ShapeDtypeStruct((), jnp.float32)
     single = _single_fn(h, w, h, w, SCOPE_CFG).lower(
         fr, wv, bv, jax.ShapeDtypeStruct((2,), jnp.float32))
     batched = _batch_fn(h, w, h, w, 4, SCOPE_CFG).lower(
-        jax.ShapeDtypeStruct((4, h, w, 3), jnp.uint8), wv, bv,
+        jax.ShapeDtypeStruct((4, h, w * 3), jnp.uint8), wv, bv,
         jax.ShapeDtypeStruct((4, 2), jnp.float32))
     names = {}
     for k, low in (("single", single), ("batched", batched)):

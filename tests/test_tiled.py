@@ -14,18 +14,22 @@ Two families, mirroring tests/test_sharded.py:
     lane forces 8 host devices via REPRO_TEST_DEVICES=8.
 """
 import dataclasses
+import pathlib
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.core import detector
 from repro.core.detector import (DetectorConfig, FrameDetector,
-                                 _autotune_key_str, _banded_put,
+                                 _autotune_key_str, _banded_put, _donate,
                                  _frame_program, _resolve_fp, _resolve_k,
-                                 _tiled_single_fn, hlo_op_names, op_scopes,
-                                 tiled_uploads)
-from repro.core.hog import PAPER_HOG
+                                 _sharded_batch_fn, _tile_mesh,
+                                 _tiled_batch_fn, _tiled_single_fn,
+                                 hlo_op_names, op_scopes, tiled_uploads)
+from repro.core.hog import PAPER_HOG, grayscale
 from repro.core.tiling import (band_rows, band_weights, extend_band,
                                merge_topk, resize_banded, scale_groups,
                                slab_pixel_rows, slab_rows)
@@ -276,6 +280,78 @@ def test_tiled_batch_matches_single_device():
     # check the key formatter the report/disk cache share)
     key = _autotune_key_str((160, 128, 160, 128, 4, base, "rgb-uint8", 2, 2))
     assert key.endswith("mesh=data:2,tile:2 [rgb-uint8]")
+
+
+def _gray_first(det, frames):
+    """Each frame's `hog.grayscale` (a gray frame as float32),
+    edge-padded to its bucket, through `_frame_program(ph, pw, cfg).raw`,
+    traced as one program; leaves stacked over the frames."""
+    h, w = frames[0].shape[:2]
+    prog, ph, pw = det.program_for(h, w)
+
+    @jax.jit
+    def ref(x, wv, bv, hw):
+        g = grayscale(x) if x.ndim == 3 else x.astype(jnp.float32)
+        return prog.raw(jnp.pad(g, ((0, ph - h), (0, pw - w)), mode="edge"),
+                        wv, bv, hw)
+
+    hw = jnp.asarray([h, w], jnp.float32)
+    outs = [[np.asarray(a) for a in ref(jnp.asarray(f), SVM["w"], SVM["b"],
+                                        hw)] for f in frames]
+    return [np.stack(leaf) for leaf in zip(*outs)]
+
+
+@multi
+@pytest.mark.parametrize("rgb", [True, False], ids=["rgb", "gray"])
+@pytest.mark.parametrize("dp,fp", [(2, 1), (2, 2)],
+                         ids=["sharded", "tiled_batch"])
+def test_sharded_and_tiled_batches_answer_as_the_gray_first_reference(
+        dp, fp, rgb):
+    """A stacked batch of 3 over dp=2 (one masked pad frame), untiled
+    (`_sharded_batch_fn`) or tiled over fp=2 (`_tiled_batch_fn`): an RGB
+    stack reaches the program as (B, h, w*3) rows, and the answers are
+    byte for byte those of the gray-first reference."""
+    if dp * fp > jax.device_count():
+        pytest.skip(f"needs {dp * fp} devices")
+    # a threshold of its own: a program is noted with the arguments of
+    # its first call, and RGB and gray frames of one size share it
+    cfg = DetectorConfig(score_threshold=-5.0625 if rgb else -5.125,
+                         scales=(1.0, 0.8), pyramid_resize="banded",
+                         batch_chunk=1, data_parallel=dp, frame_parallel=fp)
+    frames = [_frame() if rgb else _frame()[..., 0] for _ in range(3)]
+    got = FrameDetector(SVM, cfg).detect_batch_raw(np.stack(frames))
+    want = _gray_first(FrameDetector(SVM, cfg), frames)
+    assert want[0].max() > -np.inf, "no window clears the threshold"
+    leaves = jax.tree_util.tree_leaves(got)
+    assert len(leaves) == len(want) == 4
+    for a, b in zip(leaves, want):
+        assert np.array_equal(np.asarray(a), b)
+    fn = _tiled_batch_fn(160, 128, 160, 128, 4, dp, fp, cfg, _donate()) \
+        if fp > 1 else _sharded_batch_fn(160, 128, 160, 128, 4, dp, cfg,
+                                         _donate())
+    assert detector._PROGRAMS[fn]["args"][0].shape == \
+        (4, 160, 128 * 3 if rgb else 128)
+
+
+@multi
+def test_tiled_single_program_lowers_as_recorded():
+    """The single-frame tiled program, taking a 160x128 RGB frame as
+    (rows, w*3) bands over four tiles, lowers to the text recorded before
+    its gray step became the helper every frame program shares
+    (`_luma`): the in-program deinterleave changed no operation."""
+    if jax.device_count() < 4:
+        pytest.skip("needs 4 devices")
+    cfg = DetectorConfig(scales=(1.0, 0.8), frame_parallel=4,
+                         pyramid_resize="banded", score_threshold=-5.0)
+    band = NamedSharding(_tile_mesh(1, 4), P("tile"))
+    text = _tiled_single_fn(160, 128, 160, 128, 4, cfg).lower(
+        jax.ShapeDtypeStruct((160, 128 * 3), jnp.uint8, sharding=band),
+        jax.ShapeDtypeStruct((3780,), jnp.float32),
+        jax.ShapeDtypeStruct((), jnp.float32),
+        jax.ShapeDtypeStruct((2,), jnp.float32)).as_text()
+    golden = pathlib.Path(__file__).parent / "golden" / \
+        "tiled_single_160x128.mlir"
+    assert text == golden.read_text()
 
 
 @multi
