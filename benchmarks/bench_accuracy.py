@@ -12,8 +12,8 @@ the datapath it deploys); the gate (`--check`) enforces total accuracy
 >= 0.80 for every mode and |fixed - fp32| <= 1.5 points -- the fixed
 chain must not cost detection quality.
 
-Results land in BENCH_detect.json under the "accuracy" key through the
-shared merge-update writer (bench_io.py), flat scalars only.
+The table is printed, and written to `--out PATH` when one is given (the
+artifact the CI accuracy lane uploads).
 """
 from __future__ import annotations
 
@@ -23,11 +23,6 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
-
-try:                                   # package-style (python -m benchmarks.run)
-    from benchmarks.bench_io import update_bench
-except ImportError:                    # direct: python benchmarks/bench_accuracy.py
-    from bench_io import update_bench
 
 from repro.configs import hog_svm
 from repro.core.hog import HOGConfig, PAPER_HOG, hog_descriptor
@@ -61,8 +56,7 @@ def run(fast: bool = False,
                                                    neg_weight=3.0),
         ) -> Dict[str, float]:
     """Table I per numerics mode. Returns a FLAT metrics dict
-    (mode-prefixed scalar keys) and writes it to BENCH_detect.json
-    under "accuracy".
+    (mode-prefixed scalar keys).
 
     fast=True shrinks only the TRAIN split sizes of `data_cfg` via
     dataclasses.replace -- any other non-default dataset field (noise,
@@ -113,7 +107,6 @@ def run(fast: bool = False,
         print(f"table1/fixed_vs_fp32_gap_pts,{gap:+.2f},gate<= "
               f"{MAX_FIXED_GAP_PTS}")
 
-    update_bench(accuracy=metrics)
     return metrics
 
 
@@ -171,7 +164,7 @@ def main(argv=None) -> int:
                     help="exit 1 unless every mode's total accuracy >= "
                          f"{MIN_TOTAL_ACC} and the fixed-vs-fp32 gap is "
                          f"within {MAX_FIXED_GAP_PTS} points")
-    ap.add_argument("--table", type=str, default=None, metavar="PATH",
+    ap.add_argument("--out", type=str, default=None, metavar="PATH",
                     help="also write the Table I text artifact here")
     ap.add_argument("--modes", type=str, default=",".join(MODES),
                     help="comma-separated subset of: " + ",".join(MODES))
@@ -181,10 +174,12 @@ def main(argv=None) -> int:
     if unknown:
         ap.error(f"unknown modes {unknown}; available: {sorted(MODES)}")
     metrics = run(fast=a.fast, modes=modes)
-    if a.table:
+    table = format_table(metrics, modes)
+    print(table, end="")
+    if a.out:
         import pathlib
-        pathlib.Path(a.table).write_text(format_table(metrics, modes))
-        print(f"table1/artifact,{a.table},written")
+        pathlib.Path(a.out).write_text(table)
+        print(f"table1/artifact,{a.out},written")
     return check(metrics, modes) if a.check else 0
 
 

@@ -66,6 +66,7 @@ import dataclasses
 import json
 from typing import Any, Dict, Optional, Tuple
 
+from repro.configs import hog_svm
 from repro.core.cascade import CascadeConfig
 from repro.core.detector import DetectorConfig
 from repro.core.hog import HOGConfig, PAPER_HOG
@@ -182,9 +183,6 @@ def presets(name: Optional[str] = None):
 
 
 def _register_builtin() -> None:
-    # deferred import: configs/hog_svm pulls in the synthetic-data module
-    from repro.configs import hog_svm
-
     register_preset("default", PipelineConfig())
     register_preset("paper", PipelineConfig(
         name="paper", hog=hog_svm.CONFIG,

@@ -1,14 +1,13 @@
 """The paper's own workload config: the HOG+SVM detection co-processor.
 
 Geometry and numerics per Nguyen et al. (2022); PERF variant carries the
-beyond-paper §Perf settings. Used by launch/dryrun.py (--arch
-hog_svm_coproc), benchmarks/bench_accuracy.py and bench_timing.py.
+beyond-paper §Perf settings. Used by the api presets (api/config.py),
+benchmarks/bench_accuracy.py and chipbench/svm/make_svm.py.
 """
 import dataclasses
 
 from repro.core.hog import HOGConfig
 from repro.core.svm import SVMTrainConfig
-from repro.data.synth_pedestrian import PedestrianDataConfig
 
 # faithful: fp32 datapath, CORDIC magnitude/angle, NR rsqrt
 FAITHFUL = HOGConfig(mode="cordic")
@@ -24,5 +23,3 @@ PERF = dataclasses.replace(CONFIG, feat_dtype="bf16")
 QUANT = HOGConfig(mode="cordic", numerics="fixed")
 
 TRAIN = SVMTrainConfig(steps=4000, neg_weight=6.0)
-DATA = PedestrianDataConfig()          # paper split: 4202/2795, 160/134
-BATCH_PER_POD = 16384                  # dry-run serving batch (256 chips)

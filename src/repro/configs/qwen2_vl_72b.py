@@ -1,6 +1,6 @@
 """qwen2-vl-72b [vlm] -- 80L d=8192 64H (kv 8) d_ff=29568 vocab=152064,
 M-RoPE + dynamic resolution. The vision frontend (ViT patch encoder) is a
-STUB per the assignment: input_specs() provides token ids plus (B, S, 3)
+STUB per the assignment: the batch carries token ids plus (B, S, 3)
 M-RoPE (t, h, w) position streams; image patches arrive as precomputed
 embeddings merged upstream. [arXiv:2409.12191; hf]
 """

@@ -1,18 +1,7 @@
-"""Architecture registry: --arch <id> -> config, smoke config, input specs.
-
-Also defines the assigned input-shape set and the skip rules:
-  * decode shapes lower `serve_step` (one token + KV cache), not train_step
-  * long_500k requires sub-quadratic attention -> SSM/hybrid only
-  * hog_svm_coproc is the paper's own workload (batched window detection)
-"""
+"""LM architecture registry: --arch <id> -> config or smoke config."""
 from __future__ import annotations
 
-import dataclasses
 import importlib
-from typing import Dict, Optional, Tuple
-
-import jax
-import jax.numpy as jnp
 
 from repro.models.configs import ModelConfig
 
@@ -33,77 +22,6 @@ _MODULES = {a: "repro.configs." + a.replace("-", "_").replace(".", "p")
             for a in ARCH_IDS}
 
 
-@dataclasses.dataclass(frozen=True)
-class ShapeSpec:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str            # "train" | "prefill" | "decode"
-
-
-SHAPES = (
-    ShapeSpec("train_4k", 4096, 256, "train"),
-    ShapeSpec("prefill_32k", 32768, 32, "prefill"),
-    ShapeSpec("decode_32k", 32768, 128, "decode"),
-    ShapeSpec("long_500k", 524288, 1, "decode"),
-)
-SHAPE_BY_NAME = {s.name: s for s in SHAPES}
-
-
 def get_config(arch: str, smoke: bool = False) -> ModelConfig:
-    if arch == "hog_svm_coproc":
-        raise ValueError("hog_svm_coproc is handled by repro.core, "
-                         "see launch/dryrun.py")
     mod = importlib.import_module(_MODULES[arch])
     return mod.SMOKE if smoke else mod.CONFIG
-
-
-def shape_applicable(cfg: ModelConfig, shape: ShapeSpec) -> Tuple[bool, str]:
-    """(runs?, reason-if-skipped). Encodes the assignment's skip rules."""
-    if shape.name == "long_500k" and not cfg.sub_quadratic:
-        return False, "SKIP(full-attn): 500k decode needs sub-quadratic attention"
-    return True, ""
-
-
-def input_specs(cfg: ModelConfig, shape: ShapeSpec,
-                smoke: bool = False) -> Dict[str, jax.ShapeDtypeStruct]:
-    """ShapeDtypeStruct stand-ins for every model input (no allocation)."""
-    B = 4 if smoke else shape.global_batch
-    S = 32 if smoke else shape.seq_len
-    i32 = jnp.int32
-    f = jax.ShapeDtypeStruct
-
-    def tok(b, s):
-        return f((b, s), i32)
-
-    specs: Dict[str, jax.ShapeDtypeStruct] = {}
-    if shape.kind == "train":
-        specs["tokens"] = tok(B, S)
-        specs["labels"] = tok(B, S)
-        if cfg.mrope:
-            specs["positions"] = f((B, S, 3), i32)
-        if cfg.encoder_layers:
-            specs["enc_input"] = f((B, cfg.encoder_ctx, cfg.d_model),
-                                   jnp.float32)
-    elif shape.kind == "prefill":
-        specs["tokens"] = tok(B, S)
-        if cfg.mrope:
-            specs["positions"] = f((B, S, 3), i32)
-        if cfg.encoder_layers:
-            specs["enc_input"] = f((B, cfg.encoder_ctx, cfg.d_model),
-                                   jnp.float32)
-    else:  # decode: one new token against a cache of length seq_len
-        specs["token"] = tok(B, 1)
-        if cfg.encoder_layers:
-            specs["enc_states"] = f((B, cfg.encoder_ctx, cfg.d_model),
-                                    jnp.float32)
-    return specs
-
-
-def cache_specs(cfg: ModelConfig, shape: ShapeSpec,
-                smoke: bool = False) -> Dict[str, jax.ShapeDtypeStruct]:
-    """ShapeDtypeStruct for the decode-shape KV/SSM cache."""
-    from repro.models.model import init_cache
-    B = 4 if smoke else shape.global_batch
-    S = 64 if smoke else shape.seq_len
-    return jax.eval_shape(lambda: init_cache(cfg, B, S))

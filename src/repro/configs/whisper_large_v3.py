@@ -1,6 +1,6 @@
 """whisper-large-v3 [audio] -- enc-dec, 32+32L d=1280 20H (kv 20)
-d_ff=5120 vocab=51866. Conv/audio frontend is a STUB: input_specs()
-provides precomputed (B, 1500, 1280) frame embeddings per the assignment.
+d_ff=5120 vocab=51866. Conv/audio frontend is a STUB: the batch carries
+precomputed (B, 1500, 1280) frame embeddings per the assignment.
 [arXiv:2212.04356; unverified]
 """
 import dataclasses

@@ -1164,8 +1164,7 @@ def _tiled_batch_fn(h: int, w: int, ph: int, pw: int, batch: int,
 # detect_batch call on a new (true-shape, bucket, B) tuple probes each
 # candidate schedule on synthetic frames (min-of-k wall time, donation
 # off so the probe buffers survive), caches the winner for the process
-# lifetime, and exposes the decisions through autotune_report() so the
-# bench harness can record them in BENCH_detect.json.
+# lifetime, and exposes the decisions through autotune_report().
 
 _AUTOTUNE: dict = {}
 _AUTOTUNE_PROBE_ITERS = 3

@@ -1,4 +1,4 @@
-"""Batched detection pipeline -- the "co-processor" as a sharded service op.
+"""Batched detection pipeline -- the "co-processor" as a batched service op.
 
 `classify_windows(params, windows)` is the TPU equivalent of the paper's
 Fig. 6 datapath: grayscale -> HOG -> SVM -> {0, 1}, for a BATCH of windows
@@ -10,11 +10,6 @@ Execution paths (all numerically cross-validated in tests):
                    histogram, block-norm, SVM matmul as separate kernels
   * path="fused"   single fused Pallas kernel per window batch (the §Perf
                    hillclimb artifact)
-
-`shard_over_data()` places a window batch across the 'data' axis of the
-production mesh -- detection is embarrassingly data-parallel, which is the
-co-processor scaling story at pod scale (see launch/dryrun.py --arch
-hog_svm_coproc).
 """
 from __future__ import annotations
 
@@ -23,7 +18,6 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.hog import HOGConfig, PAPER_HOG
 from repro.core.svm import SVMParams, svm_score
@@ -63,20 +57,3 @@ def classify_windows(params: SVMParams, windows: Array,
         score = svm_score(params, feats)
     return {"score": score, "human": (score > 0).astype(jnp.int32)}
 
-
-def shard_over_data(mesh: Mesh, windows: Array) -> Array:
-    """Place a window batch on the mesh, batch over every data-like axis."""
-    data_axes = tuple(a for a in mesh.axis_names if a != "model")
-    spec = P(data_axes, *([None] * (windows.ndim - 1)))
-    return jax.device_put(windows, NamedSharding(mesh, spec))
-
-
-def detection_step_specs(mesh: Mesh):
-    """(in_shardings, out_shardings) for jit'ing classify_windows on a mesh."""
-    data_axes = tuple(a for a in mesh.axis_names if a != "model")
-    w_spec = {"w": NamedSharding(mesh, P(None)),
-              "b": NamedSharding(mesh, P())}
-    x_spec = NamedSharding(mesh, P(data_axes, None, None, None))
-    out_spec = {"score": NamedSharding(mesh, P(data_axes)),
-                "human": NamedSharding(mesh, P(data_axes))}
-    return (w_spec, x_spec), out_spec

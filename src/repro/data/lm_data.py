@@ -1,6 +1,6 @@
 """Synthetic LM token pipeline: structured streams a transformer can
 actually learn (Zipf unigrams + copy/induction motifs + local n-gram
-grammar), so the train_lm example shows a real loss curve offline.
+grammar), so an LM training run shows a real loss curve offline.
 """
 from __future__ import annotations
 

@@ -10,9 +10,9 @@ loose threshold, crop every firing window back to training-window
 geometry, and retrain with those crops as negatives. Two rounds drop
 the empty-scene detection count from ~20 to ~0-3 at threshold 3 while
 keeping every pedestrian -- which is what makes the two-stage cascade's
-retention/speedup contract (core/cascade.py, BENCH_detect.json
-`cascade`) meaningful. `DetectionSession.train(hard_negative_rounds=N)`
-and `train_coarse_head` drive this loop.
+retention/speedup contract (core/cascade.py) meaningful.
+`DetectionSession.train(hard_negative_rounds=N)` and `train_coarse_head`
+drive this loop.
 """
 from __future__ import annotations
 

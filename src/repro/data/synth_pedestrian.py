@@ -303,10 +303,9 @@ def make_scene(rng: np.random.Generator, h: int = 320, w: int = 240,
     """A larger scene with pasted pedestrians, for the sliding-window
     detector example. Returns (rgb uint8 (h,w,3), list of (y,x,130,66)
     boxes). `region` = (y0, x0, y1, x1) confines the paste positions to
-    a sub-rectangle -- the cascade bench (benchmarks/bench_timing.py)
-    uses it to build CLUSTERED scenes where people occupy one corner of
-    an otherwise empty frame, the sparse-traffic shape the coarse-reject
-    stage is built for."""
+    a sub-rectangle -- the cascade tests use it to build CLUSTERED
+    scenes where people occupy one corner of an otherwise empty frame,
+    the sparse-traffic shape the coarse-reject stage is built for."""
     cfg = PedestrianDataConfig()
     base = _background(rng, cfg)
     scene = np.clip(base + _smooth_noise(rng, h, w, 12)[:h, :w] * 10

@@ -7,4 +7,3 @@ from repro.kernels.svm_matmul import svm_scores, score_matmul
 from repro.kernels.fused_hog import fused_hog, dense_fused_hog
 from repro.kernels.dense_grad_hist import dense_grad_hist
 from repro.kernels.dense_block_norm import dense_block_norm
-from repro.kernels.flash_attention import flash_attention

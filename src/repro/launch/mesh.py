@@ -1,7 +1,6 @@
-"""Production mesh builders. Functions (not module constants) so importing
-never touches jax device state -- required because the dry-run forces 512
-host devices via XLA_FLAGS before any jax init, while tests/benches must
-see a single CPU device.
+"""Mesh builders. Functions (not module constants) so importing never
+touches jax device state: the device count is fixed only at first jax
+init (repro.platform may force host devices before it).
 
 `make_detection_mesh` is the detection-side default: the sharded
 detect_batch path (core/detector.py) lays its frame batch over the
@@ -11,20 +10,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import AxisType, Mesh
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
-    """16x16 single pod (256 chips) or 2x16x16 two-pod (512 chips).
-
-    Axes: ('pod',) 'data', 'model' -- see DESIGN.md §5. The 'pod' axis
-    carries only gradient all-reduces / pipeline hops (slow inter-pod
-    links); 'data' is FSDP + batch; 'model' is TP/EP/SP.
-    """
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes,
-                         axis_types=(AxisType.Auto,) * len(axes))
+from jax.sharding import Mesh
 
 
 def make_host_mesh(model: int = 1) -> Mesh:

@@ -1,25 +1,18 @@
-"""Serving launcher, two smokes behind one CLI:
+"""Detection-service launcher: builds a repro.api DetectionSession
+(training a quick SVM or loading one with --load), starts
+session.serve() -- the micro-batching DetectionService -- streams
+synthetic frames through it, and prints per-frame latency, saturation,
+and service stats. It exits nonzero when any frame came back with an
+error.
 
-LM mode (default): --arch <id> prefill + decode a batch of prompts with
-the layer-stacked KV(/SSM) cache and print tokens/s.
-
-    PYTHONPATH=src python -m repro.launch.serve --arch mamba2-130m \
-        --batch 4 --prompt-len 16 --new-tokens 32
-
-Detection mode: --detect builds a repro.api DetectionSession (training
-a quick SVM or loading one with --load), starts session.serve() -- the
-micro-batching DetectionService -- streams synthetic frames through it,
-and prints per-frame latency, saturation, and service stats. It exits
-nonzero when any frame came back with an error.
-
-    PYTHONPATH=src python -m repro.launch.serve --detect [--frames 6]
+    PYTHONPATH=src python -m repro.launch.serve [--frames 6]
         [--preset paper] [--load DIR]
 
-`--detect --chaos` replays the standard fault-injection schedule
+`--chaos` replays the standard fault-injection schedule
 (serve/faults.py chaos_specs: worker kill, device loss, latency
 spikes) through the supervised engine and exits nonzero unless every
 submitted frame resolved -- the CLI face of the chaos-smoke CI lane.
-`--detect --metrics PATH` streams the service's structured telemetry
+`--metrics PATH` streams the service's structured telemetry
 (DESIGN.md §15 event schema) to a JSONL file you can `tail -f`.
 """
 from __future__ import annotations
@@ -123,62 +116,21 @@ def _detect_smoke(args) -> int:
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default=None,
-                    help="LM serving smoke: arch id (see repro.configs)")
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=16)
-    ap.add_argument("--new-tokens", type=int, default=32)
-    ap.add_argument("--temperature", type=float, default=0.0)
-    ap.add_argument("--detect", action="store_true",
-                    help="detection-service smoke over repro.api "
-                         "(DetectionSession.serve)")
     ap.add_argument("--frames", type=int, default=6,
-                    help="frames to stream in --detect mode")
+                    help="frames to stream through the service")
     ap.add_argument("--preset", default=None,
-                    help="PipelineConfig preset for --detect")
+                    help="PipelineConfig preset")
     ap.add_argument("--chaos", action="store_true",
-                    help="--detect: run under the standard fault-"
+                    help="run under the standard fault-"
                          "injection schedule (worker kill, device "
                          "loss, latency spikes) and gate on liveness")
     ap.add_argument("--load", metavar="DIR", default=None,
-                    help="--detect: restore SVM params from a "
+                    help="restore SVM params from a "
                          "checkpoint dir instead of training")
     ap.add_argument("--metrics", metavar="PATH", default=None,
-                    help="--detect: stream service telemetry as JSONL "
+                    help="stream service telemetry as JSONL "
                          "events to PATH (DESIGN.md §15 schema)")
-    args = ap.parse_args(argv)
-
-    if args.detect:
-        return _detect_smoke(args)
-
-    import jax
-    import jax.numpy as jnp
-
-    from repro.configs import ARCH_IDS, get_config
-    from repro.models.model import init_params
-    from repro.serve.engine import generate
-
-    if args.arch not in ARCH_IDS:
-        ap.error(f"--arch is required unless --detect "
-                 f"(choices: {', '.join(ARCH_IDS)})")
-
-    cfg = get_config(args.arch, smoke=True)
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    prompt = jax.random.randint(jax.random.PRNGKey(1),
-                                (args.batch, args.prompt_len), 0, cfg.vocab)
-    enc = None
-    if cfg.encoder_layers:
-        enc = jnp.zeros((args.batch, cfg.encoder_ctx, cfg.d_model),
-                        jnp.float32)
-    t0 = time.time()
-    out = generate(params, cfg, prompt, max_new_tokens=args.new_tokens,
-                   temperature=args.temperature,
-                   key=jax.random.PRNGKey(2), enc_input=enc)
-    dt = time.time() - t0
-    print(f"arch={cfg.name}  out={out.shape}  "
-          f"{args.batch*args.new_tokens/dt:,.0f} tok/s (incl. compile)")
-    print("sample:", out[0, args.prompt_len:args.prompt_len+16].tolist())
-    return 0
+    return _detect_smoke(ap.parse_args(argv))
 
 
 if __name__ == "__main__":

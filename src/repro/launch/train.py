@@ -3,8 +3,7 @@ checkpoint/restart fault tolerance, preemption handling (SIGTERM ->
 final checkpoint -> clean exit), straggler detection (slow-step log),
 and optional DDP + int8 gradient compression.
 
-At pod scale the same step functions are compiled by launch/dryrun.py
-onto the production meshes; this driver is the single-host harness.
+This driver is the single-host harness.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-14b --smoke \
@@ -23,7 +22,7 @@ import numpy as np
 from jax.sharding import AxisType
 
 from repro.checkpoint.manager import CheckpointManager
-from repro.configs import ARCH_IDS, get_config
+from repro.configs.registry import ARCH_IDS, get_config
 from repro.data.lm_data import LMDataConfig, batches
 from repro.train.optimizer import OptConfig
 from repro.train.train_step import (init_ddp_state, init_train_state,

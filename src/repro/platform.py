@@ -2,9 +2,9 @@
 
 Before this module, five entry points each mutated `XLA_FLAGS` / env
 their own way: `tests/conftest.py` appended the forced-host-device
-flag, `benchmarks/bench_timing.py` carried its own self-forcing block,
-`launch/dryrun.py` *overwrote* `XLA_FLAGS` outright (clobbering any
-operator-set flags), and CI lanes exported ad-hoc variables. Every one
+flag, a bench script carried its own self-forcing block, a launcher
+*overwrote* `XLA_FLAGS` outright (clobbering any operator-set flags),
+and CI lanes exported ad-hoc variables. Every one
 of those is a pre-jax-init footgun: jax reads `XLA_FLAGS` exactly once,
 at first backend initialization, so a mutation that lands late is
 silently ignored and a clobber silently discards operator intent.
@@ -114,8 +114,7 @@ def force_host_devices(n: int, env: Optional[MutableMapping] = None) -> int:
     per-entry-point blocks had); warns when it cannot take effect. An
     operator-set count in XLA_FLAGS wins over `n` -- callers get the
     EFFECTIVE count back so they can assert on it. This is the one
-    implementation behind conftest's REPRO_TEST_DEVICES, the bench
-    `--sharded`/`--uhd` self-forcing, and dryrun's 512-device mesh.
+    implementation behind conftest's REPRO_TEST_DEVICES.
     """
     env = os.environ if env is None else env
     if env is os.environ and _jax_initialized() \

@@ -55,9 +55,6 @@ microbatcher, all configured by `ResilienceConfig` (inert defaults):
 Futures can never hang: every accepted request is answered exactly once
 (result, DeadlineExceeded, or a traceback-carrying error) -- on batch
 errors, worker death, breaker trips, and `stop()` with a backlog alike.
-
-`generate` -- LM serving: prefill + greedy/temperature decode loop with
-the layer-stacked KV cache. Used by examples and the serve benchmarks.
 """
 from __future__ import annotations
 
@@ -81,8 +78,6 @@ from repro.core.detector import DetectorConfig, FrameDetector
 from repro.core.hog import HOGConfig, PAPER_HOG
 from repro.core.pipeline import classify_windows
 from repro.core.svm import SVMParams
-from repro.models.configs import ModelConfig
-from repro.models.model import decode_step, prefill
 from repro.obs import spans
 from repro.obs.metrics import Emitter, MetricsConfig, make_sink
 from repro.serve.faults import DETERMINISTIC_TYPES, FaultInjector
@@ -902,44 +897,3 @@ class DetectionService:
         self.stats["breaker"] = self._breaker.snapshot()
         return True
 
-
-# -------------------------------------------------------------------- LM
-
-def generate(params: Any, cfg: ModelConfig, prompt: Array,
-             max_new_tokens: int = 32, temperature: float = 0.0,
-             key: Optional[Array] = None, ctx=None,
-             enc_input: Optional[Array] = None) -> Array:
-    """Greedy/temperature decoding. prompt: (B, S) -> (B, S + new)."""
-    B, S = prompt.shape
-    batch = {"tokens": prompt}
-    if cfg.encoder_layers:
-        batch["enc_input"] = enc_input
-    logits, cache = prefill(params, batch, cfg,
-                            max_len=S + max_new_tokens, ctx=ctx)
-    enc = None
-    if cfg.encoder_layers:
-        from repro.models.model import encode
-        enc = encode(params, enc_input, cfg, ctx)
-
-    step_fn = jax.jit(partial(decode_step, cfg=cfg, ctx=ctx))
-    toks = [prompt]
-    cur = _sample(logits[:, -1], temperature, key)
-    for t in range(max_new_tokens):
-        toks.append(cur)
-        if t == max_new_tokens - 1:
-            break
-        logits, cache = (step_fn(params, cur, cache, enc=enc)
-                         if enc is not None else
-                         step_fn(params, cur, cache))
-        if key is not None:
-            key, _ = jax.random.split(key)
-        cur = _sample(logits[:, -1], temperature, key)
-    return jnp.concatenate(toks, axis=1)
-
-
-def _sample(logits: Array, temperature: float,
-            key: Optional[Array]) -> Array:
-    if temperature <= 0.0 or key is None:
-        return jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
-    return jax.random.categorical(
-        key, logits / temperature, axis=-1)[:, None].astype(jnp.int32)

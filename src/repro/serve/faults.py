@@ -172,7 +172,7 @@ def malformed_frame(rng: np.random.Generator) -> np.ndarray:
 
 def chaos_specs(seed: int = 0) -> Tuple[FaultSpec, ...]:
     """The standard chaos-smoke scenario (CI lane `chaos-smoke` and
-    `launch.serve --detect --chaos`): one worker kill, one transient
+    `launch.serve --chaos`): one worker kill, one transient
     device loss, and a burst of latency spikes, all at fixed batch
     indices so the run replays exactly."""
     del seed  # fixed schedule; the seed knob is for prob-based plans

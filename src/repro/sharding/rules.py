@@ -162,9 +162,6 @@ def param_shardings(mesh: Mesh, params_shape: Any, cfg: ModelConfig) -> Any:
 class Profile:
     name: str = "baseline"
     seq_sharded: bool = True        # shard sequence over 'model' (SP/CP)
-    kv_shard_dim: str = "length"    # "length" | "heads" (decode cache);
-    # heads-sharding needs kv_heads % tp == 0, which no assigned arch
-    # satisfies on a 16-way axis -- length (flash-decode) is the default
     # ---- §Perf levers (see EXPERIMENTS.md §Perf) ----
     bf16_scores: bool = False       # half-width attention score tensors
     banded_window: bool = False     # block-banded sliding-window attn
@@ -175,7 +172,6 @@ class Profile:
 
 PROFILES = {
     "baseline": Profile(),
-    "kv_heads": Profile(name="kv_heads", kv_shard_dim="heads"),
     "no_seq": Profile(name="no_seq", seq_sharded=False),
     "perf": Profile(name="perf", bf16_scores=True, banded_window=True,
                     constrain_grads=True),
@@ -188,7 +184,8 @@ PROFILES = {
 
 def batch_specs(cfg: ModelConfig, mesh: Mesh, kind: str,
                 profile: Profile = PROFILES["baseline"]) -> Dict[str, P]:
-    """PartitionSpecs for the input batch dict, keyed like input_specs()."""
+    """PartitionSpecs for the input batch dict of a train, prefill or
+    decode step."""
     dp = dp_axes(mesh)
     seq = "model" if profile.seq_sharded else None
     if kind == "train":
@@ -211,20 +208,3 @@ def batch_specs(cfg: ModelConfig, mesh: Mesh, kind: str,
         sp["enc_states"] = P(dp, None, None)
     return sp
 
-
-def cache_specs_tree(cfg: ModelConfig, mesh: Mesh,
-                     profile: Profile = PROFILES["baseline"]) -> Dict[str, P]:
-    """Sharding for the KV/SSM cache pytree (leading L axis unsharded)."""
-    dp = dp_axes(mesh)
-    out: Dict[str, P] = {"idx": P()}
-    if cfg.has_attention:
-        if profile.kv_shard_dim == "length":
-            kv = P(None, dp, "model", None, None)   # (L, B, S, K, hd)
-        else:
-            kv = P(None, dp, None, "model", None)
-        out["k"] = kv
-        out["v"] = kv
-    if cfg.has_ssm:
-        out["state"] = P(None, dp, "model", None, None)  # (L,B,H,N,P)
-        out["conv"] = P(None, dp, None, None)            # (L,B,k-1,C)
-    return out

@@ -4,9 +4,7 @@ All process-level environment handling lives in `repro.platform`
 (DESIGN.md §15): importing it applies the REPRO_* knobs exactly once,
 before jax initializes -- conftest import time is safe. In particular
 REPRO_TEST_DEVICES=N forces N host devices (for the sharded /
-tiled-UHD suites); the dry-run (launch/dryrun.py) requests its own
-512-device mesh through the same seam; benches and default test runs
-see 1 device.
+tiled-UHD suites); default test runs see 1 device.
 """
 import os
 import sys

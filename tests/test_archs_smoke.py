@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.configs import ARCH_IDS, get_config
+from repro.configs.registry import ARCH_IDS, get_config
 from repro.models import (decode_step, forward, init_cache, init_params,
                           loss_fn, prefill)
 

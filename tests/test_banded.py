@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import AxisType
 
-from repro.configs import get_config
+from repro.configs.registry import get_config
 from repro.models.attention import attention, banded_attention
 from repro.models.model import forward, init_params, layer_segments
 

@@ -234,9 +234,8 @@ def _iou(a, b):
 def test_cascade_retention_on_synthetic_scene(trained):
     """Every dense-pass detection of an ACTUAL pedestrian must survive
     the cascade. The quickly-trained test head also fires on background
-    clutter far from any person -- retention of those false positives
-    is the BENCH's criterion (full training, benchmarks/bench_timing.py
-    --cascade); the unit invariant is that no true detection is lost."""
+    clutter far from any person, and those may be dropped; the
+    invariant is that no true detection is lost."""
     from repro.data.synth_pedestrian import make_scene
     sess, casc = trained
     rng = np.random.default_rng(SEED + 4)

@@ -29,7 +29,7 @@ def _mesh():
 
 @multi
 def test_moe_ep_a2a_matches_local():
-    from repro.configs import get_config
+    from repro.configs.registry import get_config
     from repro.models.model import init_params
     from repro.models.moe import ShardingCtx, moe_ffn, _moe_local
     cfg = get_config("olmoe-1b-7b", smoke=True)
@@ -54,7 +54,7 @@ def test_moe_ep_a2a_matches_local():
 
 @multi
 def test_moe_ep_replicated_matches_local():
-    from repro.configs import get_config
+    from repro.configs.registry import get_config
     from repro.models.model import init_params
     from repro.models.moe import ShardingCtx, _moe_local, _moe_ep_replicated
     cfg = get_config("olmoe-1b-7b", smoke=True)
@@ -74,7 +74,7 @@ def test_moe_ep_replicated_matches_local():
 
 @multi
 def test_gspmd_train_step_runs_and_learns():
-    from repro.configs import get_config
+    from repro.configs.registry import get_config
     from repro.train.optimizer import OptConfig
     from repro.train.train_step import (init_train_state, jit_train_step)
     cfg = get_config("qwen3-14b", smoke=True)
@@ -102,7 +102,7 @@ def test_gspmd_train_step_runs_and_learns():
 
 @multi
 def test_ddp_compressed_matches_uncompressed_direction():
-    from repro.configs import get_config
+    from repro.configs.registry import get_config
     from repro.train.optimizer import OptConfig
     from repro.train.train_step import init_ddp_state, make_ddp_train_step
     cfg = get_config("mamba2-130m", smoke=True)

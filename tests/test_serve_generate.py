@@ -4,9 +4,9 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.configs import get_config
+from repro.configs.registry import get_config
 from repro.models.model import init_params
-from repro.serve.engine import generate
+from repro.models.generate import generate
 
 
 @pytest.mark.parametrize("arch", ["mamba2-130m", "qwen3-14b", "hymba-1.5b"])

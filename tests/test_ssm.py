@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.configs import get_config
+from repro.configs.registry import get_config
 from repro.models.model import _ssm_p
 from repro.models.ssm import ssd_decode, ssd_forward
 
